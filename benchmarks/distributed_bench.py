@@ -9,10 +9,13 @@ phase (not one per selection): the regression guard for the
 fori_loop-fused BUILD, enforced wherever the bench runs (CI uploads the
 JSON as an artifact).
 
-The device-count flag must be set before jax initialises, so the
-multi-device half runs in a subprocess; results come back as JSON and
-are emitted as the usual CSV rows (and serialised to
-``BENCH_distributed.json`` by ``benchmarks/run.py --json``).
+On an accelerator the rows run in this process over its real devices:
+a chip belongs to one process, so no child may ask for it.  On the CPU
+the devices are simulated, and the device-count flag must be set before
+JAX starts, so the rows run in a child pinned to the CPU; its results
+come back as JSON.  Either way they are emitted as the usual CSV rows
+(and serialised to ``BENCH_distributed.json`` by ``benchmarks/run.py
+--json``).
 
 Knobs: ``REPRO_BENCH_DEVICES`` (simulated CPU devices, default 8),
 ``REPRO_BENCH_PALLAS=1`` adds the interpret-mode Pallas backend row
@@ -25,22 +28,22 @@ import os
 import subprocess
 import sys
 import textwrap
+import time
+
+import jax
 
 from .common import FULL, emit
 
-_CHILD = textwrap.dedent("""
-    import os, sys, json, time
-    os.environ["XLA_FLAGS"] = (
-        "--xla_force_host_platform_device_count=" + sys.argv[1])
-    n, k = int(sys.argv[2]), int(sys.argv[3])
-    backends = sys.argv[4].split(",")
+
+def rows(n: int, k: int, backends) -> dict:
+    """The sweep rows, measured in this process over every local device."""
     from repro.api import KMedoids
     from repro.core import datasets
     from repro.core.distributed import default_mesh
 
     data = datasets.make("mnist_like", n, seed=0)
     mesh = default_mesh()
-    rows = {}
+    out = {}
     cases = [("banditpam", {"baseline": "leader"}),
              ("banditpam_dist", {"mesh": mesh}),
              ("banditpam_dist[pic]", {"mesh": mesh, "reuse": "pic"})]
@@ -54,7 +57,7 @@ _CHILD = textwrap.dedent("""
             r = est.report_
             led = r.ledger()
             total = led["fresh"] + led["cached"]
-            rows[f"{name}[{backend}]"] = {
+            out[f"{name}[{backend}]"] = {
                 "loss": float(r.loss),
                 "wall_s": round(wall, 3),
                 "wall_by_phase": {p: round(v, 4)
@@ -65,7 +68,14 @@ _CHILD = textwrap.dedent("""
                 "n_swaps": int(r.n_swaps),
                 "converged": bool(r.converged),
             }
-    print(json.dumps(rows))
+    return out
+
+
+_CHILD = textwrap.dedent("""
+    import json, sys
+    from benchmarks.distributed_bench import rows
+    print(json.dumps(rows(int(sys.argv[1]), int(sys.argv[2]),
+                          sys.argv[3].split(","))))
 """)
 
 
@@ -99,23 +109,30 @@ def sweep(n=None, k=5, devices=None, backends=None):
         backends = ["jnp"]
         if os.environ.get("REPRO_BENCH_PALLAS", "0") == "1":
             backends.append("pallas")
-    out = subprocess.run(
-        [sys.executable, "-c", _CHILD, str(devices), str(n), str(k),
-         ",".join(backends)],
-        capture_output=True, text=True, timeout=1800,
-        env=dict(os.environ, PYTHONPATH="src"))
-    if out.returncode != 0:
-        raise RuntimeError(f"distributed bench child failed:\n"
-                           f"{out.stderr[-2000:]}")
-    rows = json.loads(out.stdout.strip().splitlines()[-1])
-    _assert_single_dispatch_build(rows)
-    for name, row in rows.items():
+    if jax.default_backend() == "cpu":
+        out = subprocess.run(
+            [sys.executable, "-c", _CHILD, str(n), str(k),
+             ",".join(backends)],
+            capture_output=True, text=True, timeout=1800,
+            env=dict(os.environ, PYTHONPATH=os.pathsep.join(["src", "."]),
+                     JAX_PLATFORMS="cpu",
+                     XLA_FLAGS=("--xla_force_host_platform_device_count="
+                                f"{devices}")))
+        if out.returncode != 0:
+            raise RuntimeError(f"distributed bench child failed:\n"
+                               f"{out.stderr[-2000:]}")
+        got = json.loads(out.stdout.strip().splitlines()[-1])
+    else:
+        devices = len(jax.devices())
+        got = rows(n, k, backends)
+    _assert_single_dispatch_build(got)
+    for name, row in got.items():
         emit(f"distributed_{name}_n{n}_dev{devices}", row["wall_s"] * 1e6,
              f"loss={row['loss']:.4f};fresh={row['ledger']['fresh']};"
              f"cached_frac={row['cached_fraction']};"
              f"build_dispatches={row['dispatches_by_phase'].get('build')}")
     return {"bench": "distributed", "n": int(n), "k": int(k),
-            "devices": int(devices), "rows": rows}
+            "devices": int(devices), "rows": got}
 
 
 def write_json(path="BENCH_distributed.json", **kw) -> str:

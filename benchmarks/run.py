@@ -27,6 +27,7 @@ import traceback
 
 def main(argv=None) -> None:
     from repro.api import available_solvers
+    from repro.runtime import compile_cache
 
     from . import (core_bench, distributed_bench, graphs_bench,
                    kernels_bench, loss_quality, megakernel_bench,
@@ -41,6 +42,8 @@ def main(argv=None) -> None:
                     help="restrict the solver sweep (repeatable; default: "
                          "every registered solver)")
     args = ap.parse_args(argv)
+    compile_cache.enable(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     print("name,us_per_call,derived")
     if args.json is not None:

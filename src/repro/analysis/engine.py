@@ -49,13 +49,13 @@ TRACE_TAKERS = frozenset({
     "jax.lax.while_loop", "jax.lax.fori_loop", "jax.lax.scan",
     "jax.lax.cond", "jax.lax.switch", "jax.lax.map",
     "jax.lax.associative_scan", "jax.lax.custom_root",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
 })
 
 # Decorators that make the decorated function a trace root.
 JIT_DECORATORS = frozenset({
     "jax.jit", "jax.vmap", "jax.pmap",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map",
 })
 
 _FUNC_DEFS = (ast.FunctionDef, ast.AsyncFunctionDef)

@@ -265,8 +265,13 @@ _SHARDED = frozenset({"hot", "streaming", "sharded"})
 # The sharded phases run one fori_loop-resident shard_map with three
 # moment reductions (sums / sqsums / cross-term) — the census is 3 psums
 # through 1 shard_map site, NOT one psum per phase: FastPAM1 sharing
-# needs all three moments per round (docs/design.md #4/#10).
+# needs all three moments per round (docs/design.md #4/#10).  BUILD adds
+# one collective-free replicated shard_map for its d_near row (a Mosaic
+# kernel cannot sit outside a shard_map in a partitioned program); the
+# exact-fallback passes, wrapped the same way, are never traced under
+# the permutation sampling of reuse="pic".
 _SMAP_CENSUS = {"psum": 3, "shard_map": 1}
+_BUILD_CENSUS = {"psum": 3, "shard_map": 2}
 
 
 def registry() -> Tuple[GraphSpec, ...]:
@@ -324,7 +329,7 @@ def registry() -> Tuple[GraphSpec, ...]:
                   budget="api.get_assign_fn",
                   build_big=lambda: _assign_fn(big=True)),
         GraphSpec("dist.build_phase[pic]", _dist_phase("build"), _SHARDED,
-                  collectives=_SMAP_CENSUS),
+                  collectives=_BUILD_CENSUS),
         GraphSpec("dist.swap_iter[pic]", _dist_phase("swap"), _SHARDED,
                   collectives=_SMAP_CENSUS),
     )

@@ -67,9 +67,9 @@ TRANSFER_PRIMS = frozenset({
 })
 
 # Collectives counted by GRC003; jax spells the all-reduce `psum` or
-# `psum2` depending on the axis-name context, one declared key covers
-# both.
-COLLECTIVE_PRIMS = {"psum": ("psum", "psum2"),
+# `psum2` depending on the axis-name context, and `psum_invariant` inside
+# a `shard_map` with `check_vma=True`; one declared key covers all three.
+COLLECTIVE_PRIMS = {"psum": ("psum", "psum2", "psum_invariant"),
                     "shard_map": ("shard_map",)}
 
 _FLOAT_BITS = {"float64": 64, "float32": 32, "float16": 16,

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .banditpam import _swap_terms, medoid_cache, total_loss
-from .distances import get_metric
+from .distances import EXACT, get_metric
 from .pam import pam
 from .report import FitReport
 
@@ -129,7 +129,8 @@ def _voronoi_update(data, medoids, *, metric: str, k: int):
     # One [n, n] pass, masked per cluster via one-hot matmul.
     d_all = dist(data, data)                            # [n, n]
     onehot = jax.nn.one_hot(assign, k, dtype=d_all.dtype)   # [n, k]
-    cost = d_all @ onehot                               # [n, k] Σ_{y∈C_c} d(x,y)
+    cost = jnp.matmul(d_all, onehot,                    # [n, k] Σ_{y∈C_c} d(x,y)
+                      precision=EXACT)
     member = onehot > 0
     cost = jnp.where(member, cost, jnp.inf)             # only members eligible
     nonempty = jnp.any(member, axis=0)                  # [k]

@@ -87,18 +87,9 @@ from .report import FitReport
 __all__ = ["DistributedBanditPAM", "MedoidCurator", "default_mesh"]
 
 
-if hasattr(jax, "shard_map"):                       # jax >= 0.6
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-else:                                               # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
+def _shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _data_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -306,6 +297,14 @@ class DistributedBanditPAM:
                                     P(), P()),
                           out_specs=(P(), P(), P()))
 
+    def _replicated(self, fn):
+        """``fn`` on every device of the mesh, operands and results
+        replicated.  XLA cannot partition a Mosaic kernel, so the backend
+        calls outside the sharded statistics (the exact fallback passes
+        and the d_near update) run in a ``shard_map`` of their own, each
+        device repeating the same work."""
+        return _shard_map(fn, self.mesh, in_specs=P(), out_specs=P())
+
     # -- PIC: stratified permutation layout + sharded column ring ---------
     def _pic_layout(self, n: int, ckey: jax.Array):
         """Build the ``reuse="pic"`` sampling schedule and cache buffers.
@@ -473,6 +472,12 @@ class DistributedBanditPAM:
         metric = self.metric
         B = self.batch_size
         k = self.k
+        exact_means = self._replicated(
+            lambda data, dnear: exact_build_means(be, data, dnear,
+                                                  metric=metric))
+        dist_row = self._replicated(
+            lambda data, m: be.pairwise(data[m][None, :], data,
+                                        metric=metric)[0])
 
         @jax.jit
         def build_phase(data, data_sh, base_key, subkeys, lperm, lw,
@@ -489,8 +494,7 @@ class DistributedBanditPAM:
 
                     sr = adaptive_search(
                         subkeys[i], stats_fn=stats_fn,
-                        exact_fn=lambda: exact_build_means(
-                            be, data, dnear, metric=metric),
+                        exact_fn=lambda: exact_means(data, dnear),
                         n_arms=n, n_ref=n, batch_size=B, delta=delta,
                         active_init=jnp.logical_not(med_mask),
                         sampling="permutation", baseline="leader",
@@ -509,17 +513,14 @@ class DistributedBanditPAM:
 
                     sr = adaptive_search(
                         subkeys[i], stats_fn=stats_fn,
-                        exact_fn=lambda: exact_build_means(
-                            be, data, dnear, metric=metric),
+                        exact_fn=lambda: exact_means(data, dnear),
                         n_arms=n, n_ref=n, batch_size=B, delta=delta,
                         active_init=jnp.logical_not(med_mask),
                         sampling="replacement", baseline="leader")
                 m = sr.best
                 medoids = medoids.at[i].set(m)
                 med_mask = med_mask.at[m].set(True)
-                dnear = jnp.minimum(
-                    dnear,
-                    be.pairwise(data[m][None, :], data, metric=metric)[0])
+                dnear = jnp.minimum(dnear, dist_row(data, m))
                 if mode == "pic":
                     # Fresh POSITION count; the host multiplies by n
                     # (a device uint32 n·Δ product would wrap at large n).
@@ -559,6 +560,9 @@ class DistributedBanditPAM:
         B = self.batch_size
         b_loc = B // self.n_shards
         k = self.k
+        exact_means = self._replicated(
+            lambda data, d1, d2, assign: exact_swap_means(
+                be, data, d1, d2, assign, k, metric=metric))
 
         @jax.jit
         def swap_iter(data, data_sh, medoids, med_mask, phase_key,
@@ -600,8 +604,7 @@ class DistributedBanditPAM:
                 return jnp.sum(any_x.astype(jnp.uint32))
 
             def exact_fn():
-                return exact_swap_means(be, data, d1, d2, assign, k,
-                                        metric=metric)
+                return exact_means(data, d1, d2, assign)
 
             if mode == "pic":
                 def stats_fn(ref_idx, w, lead, rnd, aux):

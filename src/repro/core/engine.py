@@ -52,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .distances import get_metric, pairwise
+from .distances import EXACT, get_metric, pairwise
 from .pic_cache import (PicCache, cache_read_or_write, carry_valid,  # noqa: F401
                         fresh_positions, make_cache,  # noqa: F401
                         resolve_cache_rounds)  # noqa: F401
@@ -301,15 +301,17 @@ def _swap_batch_stats(dxy, d1_b, d2_b, a_b, w, k, lead=None):
     # enough for every product below.
     base = base * w[None, :]
     onehot = jax.nn.one_hot(a_b, k, dtype=dxy.dtype) * w[:, None]   # [B, k]
-    sums = jnp.sum(base, axis=1)[None, :] + (corr @ onehot).T       # [k, n]
+    mm = lambda a, b: jnp.matmul(a, b, precision=EXACT)
+    sums = jnp.sum(base, axis=1)[None, :] + mm(corr, onehot).T      # [k, n]
     sq_base = jnp.sum(base * base, axis=1)
     sq_cross = 2.0 * base * corr + corr * corr
-    sqsums = sq_base[None, :] + (sq_cross @ onehot).T
+    sqsums = sq_base[None, :] + mm(sq_cross, onehot).T
     if lead is None:
         return sums.reshape(-1), sqsums.reshape(-1)
     m_l, x_l = lead // n, lead % n
     g_lead = base[x_l] + onehot[:, m_l] * corr[x_l]                 # [B], w-masked
-    cross = (base @ g_lead)[None, :] + ((corr * g_lead[None, :]) @ onehot).T
+    cross = (mm(base, g_lead)[None, :]
+             + mm(corr * g_lead[None, :], onehot).T)
     return sums.reshape(-1), sqsums.reshape(-1), cross.reshape(-1)
 
 
@@ -384,7 +386,7 @@ class JnpStatsBackend:
         ``lead=None`` skips the leader cross-sum (baseline="none")."""
         g = _build_g(dxy, dnear_b) * w[None, :]                     # [n, B]
         cross = (jnp.zeros((g.shape[0],), g.dtype) if lead is None
-                 else g @ g[lead])
+                 else jnp.matmul(g, g[lead], precision=EXACT))
         return jnp.sum(g, axis=1), jnp.sum(g * g, axis=1), cross
 
     # -- SWAP (FastPAM1 fused form) -------------------------------------
@@ -445,6 +447,9 @@ class PallasStatsBackend:
     # -- BUILD ----------------------------------------------------------
     def build_stats(self, data, ref_idx, dnear_b, w, lead, *, metric):
         from repro.kernels import ops
+        if not ops.gstats_fit(self.tm, ref_idx.shape[0], data.shape[1], 1):
+            return JnpStatsBackend.build_stats(self, data, ref_idx, dnear_b,
+                                               w, lead, metric=metric)
         y = data[ref_idx]
         if lead is None:
             lead_g = None
@@ -471,6 +476,10 @@ class PallasStatsBackend:
     def swap_stats(self, data, ref_idx, d1_b, d2_b, assign_b, w, k, lead,
                    *, metric):
         from repro.kernels import ops
+        if not ops.gstats_fit(self.tm, ref_idx.shape[0], data.shape[1], k):
+            return JnpStatsBackend.swap_stats(self, data, ref_idx, d1_b, d2_b,
+                                              assign_b, w, k, lead,
+                                              metric=metric)
         n = data.shape[0]
         y = data[ref_idx]
         if lead is None:
@@ -487,6 +496,9 @@ class PallasStatsBackend:
 
     def swap_stats_from_d(self, dxy, d1_b, d2_b, assign_b, w, k, lead):
         from repro.kernels import ops
+        if not ops.cached_fit(self.tm, dxy.shape[1], k):
+            return JnpStatsBackend.swap_stats_from_d(self, dxy, d1_b, d2_b,
+                                                     assign_b, w, k, lead)
         n = dxy.shape[0]
         if lead is None:
             lead_g = None
@@ -499,16 +511,19 @@ class PallasStatsBackend:
         return s.reshape(-1), q.reshape(-1), c.reshape(-1)
 
     # -- streaming contract ---------------------------------------------
-    def _stream_ok(self, d: int, metric: str) -> bool:
+    def _stream_ok(self, n: int, d: int, k: int, metric: str) -> bool:
         # The streaming kernels hold both operand tiles feature-resident
-        # (g-statistics are not additive across feature chunks), so very
-        # wide inputs fall back to the tiled jnp walk.
+        # (g-statistics are not additive across feature chunks): inputs
+        # whose tiles the tuner cannot fit in scoped VMEM (kernels/vmem.py)
+        # take the tiled jnp walk.
         from repro.kernels import ops
-        return metric in ops.KERNEL_METRICS and -(-d // 128) * 128 <= ops.DK_MAX
+        cfg = resolve_tile_config(n, d, k, backend="pallas")
+        return (metric in ops.KERNEL_METRICS
+                and ops.gstats_fit(cfg.tm, cfg.tb, d, k))
 
     def stream_build_sums(self, data, dnear, *, metric):
         from repro.kernels import ops
-        if not self._stream_ok(data.shape[1], metric):
+        if not self._stream_ok(*data.shape, 1, metric):
             return _stream_build_sums_jnp(data, dnear, metric=metric)
         s, _, _ = ops.stream_build_g_stats(data, data, dnear, metric=metric,
                                            interpret=self.interpret)
@@ -516,7 +531,7 @@ class PallasStatsBackend:
 
     def stream_swap_sums(self, data, d1, d2, assign, k, *, metric):
         from repro.kernels import ops
-        if not self._stream_ok(data.shape[1], metric):
+        if not self._stream_ok(*data.shape, k, metric):
             return _stream_swap_sums_jnp(data, d1, d2, assign, k,
                                          metric=metric)
         s, _, _ = ops.stream_swap_g_stats(data, data, d1, d2, assign, None,
@@ -526,7 +541,7 @@ class PallasStatsBackend:
 
     def top2(self, x, med_pts, *, metric):
         from repro.kernels import ops
-        if not self._stream_ok(x.shape[1], metric):
+        if not self._stream_ok(*x.shape, med_pts.shape[0], metric):
             return _stream_top2_jnp(x, med_pts, metric=metric)
         return ops.stream_top2(x, med_pts, metric=metric,
                                interpret=self.interpret)

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .distances import EXACT
 from .engine import get_stats_backend, resolve_stats_backend, total_loss
 from .report import FitReport
 
@@ -100,7 +101,8 @@ def _onebatch_solve(D, init_meds, *, k: int, max_swaps: int, do_build: bool):
         base = md - d1[None, :]                             # [n, b]
         corr = jnp.minimum(D, d2[None, :]) - md
         onehot = jax.nn.one_hot(a_b, k, dtype=D.dtype)      # [b, k]
-        delta = jnp.sum(base, axis=1)[:, None] + corr @ onehot   # [n, k]
+        delta = (jnp.sum(base, axis=1)[:, None]                  # [n, k]
+                 + jnp.matmul(corr, onehot, precision=EXACT))
         delta = jnp.where(mask[:, None], jnp.inf, delta)
         best = jnp.argmin(delta.reshape(-1))
         x, m = best // k, best % k

@@ -1,7 +1,7 @@
 """Backend-aware tile tuner for the streaming g-stats megakernel.
 
 The streaming kernels (``repro.kernels.stream_g``) and their jnp
-equivalents walk the reference set in tiles; three knobs shape the walk:
+equivalents walk the reference set in tiles; two knobs shape the walk:
 
 * ``tm`` — candidate-tile rows (one grid program owns a [tm, ·] strip).
 * ``tb`` — reference-tile width.  **Pinned to ``REF_TILE`` (512, the
@@ -10,11 +10,15 @@ equivalents walk the reference set in tiles; three knobs shape the walk:
   tiles in walk order", so changing ``tb`` regroups the f32 adds and
   forfeits bit-parity with the ledger fixtures.  It is a knob for
   throwaway sweeps only.
-* ``dk`` — feature-axis residency budget.  The streaming kernels hold
-  both operand tiles ([tm, d] and [tb, d]) in VMEM for the whole walk;
-  feature dims past ``dk`` fall back to the tiled-jnp path (g is not
-  additive across feature chunks, so unlike ``pairwise_distance`` the
-  fused kernels cannot split d).
+
+The feature axis is not a knob: the streaming kernels hold both operand
+tiles ([tm, d] and [tb, d]) in VMEM for the whole walk, double-buffered,
+and g is not additive across feature chunks, so unlike
+``pairwise_distance`` they cannot split d.  Shapes whose tiles exceed the
+16 MiB scoped VMEM limit even at the smallest ``tm`` take the tiled-jnp
+path.  At k <= 128 that admits padded d up to 1920 at tm=128, 1152 at
+tm=256 and 512 at tm=512 (``repro.kernels.vmem``; compiled for a v5e
+chip by ``tests/test_tpu_compile.py``).
 
 ``resolve_tile_config`` is the single resolution point, keyed on
 ``(n, d, k, device kind, backend)``.  It consults a measured ledger
@@ -37,17 +41,13 @@ from typing import Dict, Iterable, Optional, Tuple
 
 import jax
 
+from repro.kernels import vmem
+
 # Reference-tile width every parity-checked streaming path is pinned to.
 # MUST stay equal to repro.core.engine._EXACT_CHUNK (asserted there): the
 # jnp scan chunks and the kernel grid walk share these boundaries so both
 # backends accumulate per-arm sums in the same order.
 REF_TILE = 512
-
-# Per-core VMEM budget the heuristic packs operand tiles into.  Real TPU
-# cores have ~64–128 MiB; staying near 16 MiB leaves room for the
-# pipeline's double buffering (two in-flight copies of every operand
-# tile) plus output blocks.
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 _TM_CANDIDATES = (128, 256, 512)
 
@@ -58,7 +58,6 @@ class TileConfig:
 
     tm: int             # candidate-tile rows
     tb: int = REF_TILE  # reference-tile width (parity-pinned default)
-    dk: int = 8192      # max resident feature width (lane multiple)
 
 
 def _bucket(v: int) -> int:
@@ -79,25 +78,24 @@ _LEDGER: Dict[Tuple, Dict[TileConfig, float]] = {}
 
 def heuristic(n: int, d: int, k: int, device_kind: Optional[str] = None,
               backend: str = "jnp") -> TileConfig:
-    """VMEM-budget default: the largest ``tm`` whose resident set
-    (x-tile + y-tile + stat blocks, f32) fits the budget.  On CPU the
-    Pallas kernels run in interpret mode where bigger tiles only grow
-    the emulated working set, so ``tm`` stays at the floor."""
+    """VMEM-budget default: the largest ``tm`` whose streaming kernels
+    fit the scoped VMEM limit, counting double-buffered operand tiles,
+    output blocks and compiler scratch (``vmem.gstats_bytes``).  Where
+    even the smallest ``tm`` does not fit, it is returned anyway and the
+    stats backend takes the jnp walk.  On CPU the Pallas kernels run in
+    interpret mode where bigger tiles only grow the emulated working
+    set, so ``tm`` stays at the floor."""
     if device_kind is None:
         device_kind = jax.default_backend()
-    d_pad = -(-max(int(d), 1) // 128) * 128
-    kp = -(-max(int(k), 1) // 128) * 128
     if backend == "pallas" and device_kind == "cpu":
-        return TileConfig(tm=_TM_CANDIDATES[0], dk=d_pad)
+        return TileConfig(tm=_TM_CANDIDATES[0])
     tm = _TM_CANDIDATES[0]
     for cand in _TM_CANDIDATES:
         if cand > max(int(n), 1):
             break
-        resident = 4 * (cand * d_pad + REF_TILE * d_pad
-                        + 3 * cand * kp)          # x + y + stat blocks
-        if resident <= VMEM_BUDGET_BYTES:
+        if vmem.fits(vmem.gstats_bytes(cand, REF_TILE, d, k)):
             tm = cand
-    return TileConfig(tm=tm, dk=d_pad if d_pad <= 8192 else 8192)
+    return TileConfig(tm=tm)
 
 
 def candidates(n: int, d: int, k: int, device_kind: Optional[str] = None,
@@ -106,7 +104,8 @@ def candidates(n: int, d: int, k: int, device_kind: Optional[str] = None,
     base = heuristic(n, d, k, device_kind, backend)
     seen = []
     for tm in _TM_CANDIDATES:
-        if tm <= max(int(n), 1) * 2:
+        if (tm <= max(int(n), 1) * 2
+                and vmem.fits(vmem.gstats_bytes(tm, base.tb, d, k))):
             cfg = dataclasses.replace(base, tm=tm)
             if cfg not in seen:
                 seen.append(cfg)

@@ -13,7 +13,12 @@ distance tile never leaves VMEM — on a v5e this turns an HBM-bound
 O(n·B) tensor round-trip into three O(n) vectors (arithmetic intensity
 rises from ~1 flop/byte to ~B flops/byte on the output side).
 
-VMEM at TM=128, B=512, D=1024: x 512 KiB + y 2 MiB + tile 256 KiB.
+VMEM: the pipeline buffers the x tile, the resident [B, D] batch and
+the vectors, and the fp32 matmul needs split copies of the x tile, all
+under the compiler's 16 MiB scoped limit.  At TM=128 and a round batch
+of B <= 128 the rule admits padded D up to 3840 (``ops.gstats_fit``;
+compiled for a v5e chip in ``tests/test_tpu_compile.py``); wider inputs
+take the jnp statistics.
 """
 
 from __future__ import annotations
@@ -24,18 +29,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pairwise import dist_tile
+from .pairwise import EXACT, dist_tile
 
 
 def _kernel(x_ref, y_ref, dn_ref, w_ref, lg_ref, sums_ref, sq_ref, cross_ref,
             *, metric):
-    d = dist_tile(x_ref[...], y_ref[...], metric)        # [TM, B]
+    d = dist_tile(x_ref, y_ref, metric)        # [TM, B]
     dn = dn_ref[0, :][None, :]                            # [1, B]
     w = w_ref[0, :][None, :]
     g = jnp.where(jnp.isinf(dn), d, jnp.minimum(d - dn, 0.0)) * w
     sums_ref[0, :] = jnp.sum(g, axis=1)
     sq_ref[0, :] = jnp.sum(g * g, axis=1)
-    cross_ref[0, :] = g @ lg_ref[0, :]
+    cross_ref[0, :] = jnp.dot(g, lg_ref[0, :], precision=EXACT)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "tm", "interpret"))

@@ -20,6 +20,7 @@ from . import build_g as _build_g
 from . import pairwise as _pairwise
 from . import stream_g as _stream_g
 from . import swap_g as _swap_g
+from . import vmem as _vmem
 
 
 # Metrics implemented by the Pallas kernels (the registry-facing names;
@@ -27,10 +28,11 @@ from . import swap_g as _swap_g
 # resolution both key off this tuple).
 KERNEL_METRICS = ("l2", "l2sq", "l1", "cosine")
 
-# Feature-axis tile budget: one [128, DK_MAX] f32 operand tile is 4 MiB of
-# VMEM.  Larger feature dims are split into dk-chunks whose additive cores
-# (squared distances / abs-sums / dot products) accumulate exactly.
-DK_MAX = 8192
+# Feature-axis tile budget: the widest lane-multiple d whose 128x128
+# pairwise tile fits the scoped VMEM limit (``vmem``).  Larger feature
+# dims are split into dk-chunks whose additive cores (squared distances /
+# abs-sums / dot products) accumulate exactly.
+DK_MAX = _vmem.max_feature_width(lambda d: _vmem.pairwise_bytes(128, 128, d))
 
 
 def _default_interpret() -> bool:
@@ -243,12 +245,26 @@ def _stream_tiles(n, d, k, tm, tb):
     return tm, tb
 
 
-def _check_stream_d(d_pad: int, what: str) -> None:
-    if d_pad > DK_MAX:
+def gstats_fit(tm: int, tb: int, d: int, k: int) -> bool:
+    """Whether a g-statistics kernel (one-shot with a ``tb``-row batch,
+    or streaming with ``tb``-row reference tiles) fits the scoped VMEM
+    limit at feature width ``d`` and ``k`` medoids (``vmem.gstats_bytes``)."""
+    return _vmem.fits(_vmem.gstats_bytes(tm, tb, d, k))
+
+
+def cached_fit(tm: int, b: int, k: int) -> bool:
+    """Whether the cache-served SWAP kernel fits at block width ``b``
+    (split into ``CACHE_B_MAX`` chunks past it)."""
+    return _vmem.fits(_vmem.cached_swap_bytes(tm, min(b, CACHE_B_MAX), k))
+
+
+def _check_stream(tm: int, tb: int, d: int, k: int, what: str) -> None:
+    if not gstats_fit(tm, tb, d, k):
         raise ValueError(
-            f"{what} holds both operand tiles feature-resident; padded "
-            f"d={d_pad} exceeds the dk budget {DK_MAX} (g-statistics are "
-            f"not additive across feature chunks) — use the tiled jnp "
+            f"{what} holds both operand tiles feature-resident; tm={tm}, "
+            f"tb={tb}, d={d}, k={k} exceed the dk budget of "
+            f"{_vmem.SCOPED_VMEM_BYTES} bytes of scoped VMEM (g-statistics "
+            f"are not additive across feature chunks) — use the tiled jnp "
             f"streaming path for wider features")
 
 
@@ -274,7 +290,7 @@ def stream_build_g_stats(x: jnp.ndarray, yref: jnp.ndarray,
         lead_g = jnp.zeros((r,), jnp.float32)
     xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
     yp = _pad_to(_pad_to(yref, 1, 128), 0, tb)
-    _check_stream_d(xp.shape[1], "stream_build_g_stats")
+    _check_stream(tm, tb, d, 1, "stream_build_g_stats")
     pad_r = yp.shape[0] - r
     dn = jnp.pad(dnear, (0, pad_r))
     wp = jnp.pad(w, (0, pad_r))               # padded refs get weight 0
@@ -304,7 +320,7 @@ def stream_swap_g_stats(x: jnp.ndarray, yref: jnp.ndarray, d1: jnp.ndarray,
         w = jnp.ones((r,), jnp.float32)
     xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
     yp = _pad_to(_pad_to(yref, 1, 128), 0, tb)
-    _check_stream_d(xp.shape[1], "stream_swap_g_stats")
+    _check_stream(tm, tb, d, k, "stream_swap_g_stats")
     d1p, d2p, oh, lg = _swap_prep(d1, d2, assign, w, k, lead_g,
                                   yp.shape[0] - r, row_mult=tb)
     sums, sq, cross = _stream_g.stream_swap_g_kernel(
@@ -325,10 +341,10 @@ def stream_top2(x: jnp.ndarray, med_pts: jnp.ndarray, *, metric: str = "l2",
         interpret = _default_interpret()
     n, d = x.shape
     k = med_pts.shape[0]
-    tm, _ = _stream_tiles(n, d, k, tm, None)
+    tm, tb = _stream_tiles(n, d, k, tm, None)
     xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
     mp = _pad_to(_pad_to(med_pts, 1, 128), 0, 128)
-    _check_stream_d(xp.shape[1], "stream_top2")
+    _check_stream(tm, tb, d, k, "stream_top2")
     kmask = jnp.pad(jnp.ones((k,), jnp.float32), (0, mp.shape[0] - k))
     d1, d2, a = _stream_g.stream_top2_kernel(xp, mp, kmask, metric=metric,
                                              tm=tm, interpret=interpret)

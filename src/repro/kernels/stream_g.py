@@ -24,9 +24,13 @@ added in walk order.  With ``tb`` pinned to the engine's historical
 ``_EXACT_CHUNK`` (see ``repro.core.tuning.REF_TILE``) this reproduces
 the chunked ``lax.scan`` ledgers bit-for-bit; see docs/design.md #8.
 
-VMEM at tm=tb=512, d=1024, f32: x-tile 2 MiB + y-tile 2 MiB (x2 for the
-pipeline) + stat blocks < 1 MiB — the tuner (``repro.core.tuning``)
-sizes tm/dk against this budget per (n, d, k, device kind).
+VMEM: both operand tiles and every per-reference vector are
+double-buffered, the [tm, tb] temporaries and the fp32 matmul's operand
+splits need compiler scratch, all under the compiler's 16 MiB scoped
+limit.  At tb=512 and k <= 128 the rule (``vmem.gstats_bytes``) admits
+padded d up to 1920 at tm=128, 1152 at tm=256 and 512 at tm=512, each
+compiled for a v5e chip.  The tuner (``repro.core.tuning``) picks the
+largest tm that fits; past d=1920 the stats backend takes the jnp walk.
 """
 
 from __future__ import annotations
@@ -37,14 +41,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pairwise import dist_tile
+from .pairwise import EXACT, dist_tile
 from .swap_g import swap_stats_vals
 
 
 def _build_kernel(x_ref, y_ref, dn_ref, w_ref, lg_ref,
                   sums_ref, sq_ref, cross_ref, *, metric):
     j = pl.program_id(1)
-    d = dist_tile(x_ref[...], y_ref[...], metric)         # [TM, TB]
+    d = dist_tile(x_ref, y_ref, metric)         # [TM, TB]
     dn = dn_ref[0, :][None, :]
     w = w_ref[0, :][None, :]
     g = jnp.where(jnp.isinf(dn), d, jnp.minimum(d - dn, 0.0)) * w
@@ -57,7 +61,7 @@ def _build_kernel(x_ref, y_ref, dn_ref, w_ref, lg_ref,
 
     sums_ref[0, :] += jnp.sum(g, axis=1)
     sq_ref[0, :] += jnp.sum(g * g, axis=1)
-    cross_ref[0, :] += g @ lg_ref[0, :]
+    cross_ref[0, :] += jnp.dot(g, lg_ref[0, :], precision=EXACT)
 
 
 @functools.partial(jax.jit,
@@ -95,7 +99,7 @@ def stream_build_g_kernel(x, y, dnear, w, lead_g, *, metric: str,
 def _swap_kernel(x_ref, y_ref, d1_ref, d2_ref, oh_ref, lg_ref,
                  sums_ref, sq_ref, cross_ref, *, metric):
     j = pl.program_id(1)
-    d = dist_tile(x_ref[...], y_ref[...], metric)         # [TM, TB]
+    d = dist_tile(x_ref, y_ref, metric)         # [TM, TB]
     sums, sq, cross = swap_stats_vals(d, d1_ref[0, :], d2_ref[0, :],
                                       oh_ref[...], lg_ref[0, :])
 
@@ -147,7 +151,7 @@ def stream_swap_g_kernel(x, y, d1, d2, onehot_w, lead_g, *, metric: str,
 
 def _top2_kernel(x_ref, med_ref, mask_ref, d1_ref, d2_ref, a_ref, *,
                  metric):
-    d = dist_tile(x_ref[...], med_ref[...], metric)       # [TM, KP]
+    d = dist_tile(x_ref, med_ref, metric)       # [TM, KP]
     kp = d.shape[1]
     d = jnp.where(mask_ref[0, :][None, :] > 0.0, d, jnp.inf)
     d1 = jnp.min(d, axis=1)

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pairwise import dist_tile
+from .pairwise import EXACT, dist_tile
 
 
 def swap_stats_vals(d, d1, d2, oh, lg):
@@ -48,11 +48,13 @@ def swap_stats_vals(d, d1, d2, oh, lg):
     base = (jnp.minimum(d, d1) - d1) * w
     corr = jnp.minimum(d, d2) - jnp.minimum(d, d1)
     dot = lambda a: jax.lax.dot_general(
-        a, oh, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        a, oh, (((1,), (0,)), ((), ())), precision=EXACT,
+        preferred_element_type=jnp.float32)
     sums = jnp.sum(base, 1, keepdims=True) + dot(corr)
     sq = jnp.sum(base * base, 1, keepdims=True) + dot(
         2.0 * base * corr + corr * corr)
-    cross = (base @ lg)[:, None] + dot(corr * lg[None, :])
+    cross = (jnp.dot(base, lg, precision=EXACT)[:, None]
+             + dot(corr * lg[None, :]))
     return sums, sq, cross
 
 
@@ -68,7 +70,7 @@ def _stats_from_d(d, d1_ref, d2_ref, oh_ref, lg_ref,
 
 def _kernel(x_ref, y_ref, d1_ref, d2_ref, oh_ref, lg_ref,
             sums_ref, sq_ref, cross_ref, *, metric):
-    d = dist_tile(x_ref[...], y_ref[...], metric)        # [TM, B]
+    d = dist_tile(x_ref, y_ref, metric)        # [TM, B]
     _stats_from_d(d, d1_ref, d2_ref, oh_ref, lg_ref,
                   sums_ref, sq_ref, cross_ref)
 

@@ -9,12 +9,6 @@ import textwrap
 import jax
 import pytest
 
-if not hasattr(jax, "shard_map"):
-    # repro.train.compressed drives partial-auto shard_map via the jax>=0.6
-    # top-level API; on older jax the experimental fallback aborts inside
-    # this XLA build's SPMD partitioner (HandleWhile), so skip cleanly.
-    pytest.skip("needs jax.shard_map (jax >= 0.6)", allow_module_level=True)
-
 _SUBPROC = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

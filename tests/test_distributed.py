@@ -13,13 +13,6 @@ import pytest
 
 from repro.distributed.compression import dequantize_int8, quantize_int8
 
-# The mesh axis_types / top-level shard_map API needs jax >= 0.6; the pure
-# compression-math tests below run everywhere.
-requires_modern_jax = pytest.mark.skipif(
-    not (hasattr(jax, "shard_map") and hasattr(jax.sharding, "AxisType")),
-    reason="needs jax.shard_map / jax.sharding.AxisType (jax >= 0.6)")
-
-
 def test_quantize_roundtrip_error_bound():
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((64, 64)).astype(np.float32))
@@ -74,7 +67,6 @@ _SUBPROC = textwrap.dedent("""
 """)
 
 
-@requires_modern_jax
 def test_compressed_psum_matches_exact_subprocess():
     out = subprocess.run([sys.executable, "-c", _SUBPROC], capture_output=True,
                          text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
@@ -92,7 +84,6 @@ def test_sharding_rules_noop_without_mesh():
     assert spec_for("batch") == jax.sharding.PartitionSpec()
 
 
-@requires_modern_jax
 def test_spec_for_with_mesh_rules():
     from repro.distributed import sharding as sh
     # fake mesh context: use the 1-device mesh but full rule table

@@ -10,12 +10,6 @@ import textwrap
 import jax
 import pytest
 
-if not hasattr(jax.sharding, "AxisType"):
-    # The mesh axis_types API used by this subprocess needs jax >= 0.6;
-    # skip cleanly on older installs.
-    pytest.skip("needs jax.sharding.AxisType (jax >= 0.6)",
-                allow_module_level=True)
-
 _SUBPROC = textwrap.dedent("""
     import os, tempfile
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

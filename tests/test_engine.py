@@ -70,6 +70,40 @@ def test_backend_registry_and_resolution():
         resolve_stats_backend("pallas", "precomputed")
 
 
+@pytest.mark.parametrize("method", ["build_stats", "swap_stats",
+                                    "stream_build_sums", "stream_swap_sums",
+                                    "top2"])
+def test_pallas_backend_takes_jnp_path_past_vmem_rule(method):
+    """A width whose tiles the kernels' VMEM rule refuses (d=8192) runs
+    the jnp statistics inside the pallas backend, with jnp's results."""
+    from repro.core.engine import get_stats_backend
+    from repro.kernels import ops
+    n, d, k = 40, 8192, 3
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(n, d)),
+                    jnp.float32)
+    ref_idx = jnp.arange(0, n, 2)
+    b = ref_idx.shape[0]
+    assert not ops.gstats_fit(128, b, d, k)
+    assert not ops.gstats_fit(128, 512, d, k)
+    d1 = jnp.linspace(1.0, 2.0, n)
+    assign = jnp.arange(n, dtype=jnp.int32) % k
+    w = jnp.ones((b,), jnp.float32)
+    args = {
+        "build_stats": (x, ref_idx, d1[ref_idx], w, None),
+        "swap_stats": (x, ref_idx, d1[ref_idx], 2 * d1[ref_idx],
+                       assign[ref_idx], w, k, None),
+        "stream_build_sums": (x, d1),
+        "stream_swap_sums": (x, d1, 2 * d1, assign, k),
+        "top2": (x, x[:k]),
+    }[method]
+    got = getattr(get_stats_backend("pallas"), method)(*args, metric="l2")
+    want = getattr(get_stats_backend("jnp"), method)(*args, metric="l2")
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-6, atol=1e-3)
+
+
 # ---------------------------------------------------------------------------
 # Fused driver: single-jit BUILD, fused-vs-stepped equivalence
 # ---------------------------------------------------------------------------

@@ -90,14 +90,13 @@ def test_grc002_clean_on_streamed_form_and_untagged():
 
 
 def _psum_fn():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     mesh = Mesh(jax.devices()[:1], ("i",))
 
     @jax.jit
     def f(x):
-        return shard_map(lambda a: jax.lax.psum(a, "i"), mesh=mesh,
-                         in_specs=P("i"), out_specs=P())(x)
+        return jax.shard_map(lambda a: jax.lax.psum(a, "i"), mesh=mesh,
+                             in_specs=P("i"), out_specs=P())(x)
     return f
 
 
