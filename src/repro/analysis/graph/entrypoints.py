@@ -100,9 +100,10 @@ def _driver_statics(**over):
 def _pic_cache_avals(bf: Optional[int] = None):
     from repro.core.pic_cache import PicCache
     if bf is None:
-        return PicCache(cols=_f32(N, WIDTH), hw=_i32(), fresh_pos=_u32())
+        return PicCache(cols=_f32(N, WIDTH), hw=_i32(), fresh_pos=_u32(),
+                        refresh_pos=_u32())
     return PicCache(cols=_f32(bf, N, WIDTH), hw=_i32(bf),
-                    fresh_pos=_u32(bf))
+                    fresh_pos=_u32(bf), refresh_pos=_u32(bf))
 
 
 # -- core drivers -----------------------------------------------------------
@@ -340,7 +341,8 @@ def _big_driver_build():
     n, d, k = budgets.N_DRIVER, budgets.D_DRIVER, budgets.K_DRIVER
     width = budgets.WIDTH_DRIVER
     from repro.core.pic_cache import PicCache
-    cache = PicCache(cols=_f32(n, width), hw=_i32(), fresh_pos=_u32())
+    cache = PicCache(cols=_f32(n, width), hw=_i32(), fresh_pos=_u32(),
+                     refresh_pos=_u32())
     kw = dict(backend="jnp", metric="l2", batch_size=B,
               delta=1.0 / (1000.0 * n), sampling="permutation",
               baseline="none", k=k, mode="pic", free_rounds=0)
@@ -353,7 +355,8 @@ def _big_driver_swap():
     n, d, k = budgets.N_DRIVER, budgets.D_DRIVER, budgets.K_DRIVER
     width = budgets.WIDTH_DRIVER
     from repro.core.pic_cache import PicCache
-    cache = PicCache(cols=_f32(n, width), hw=_i32(), fresh_pos=_u32())
+    cache = PicCache(cols=_f32(n, width), hw=_i32(), fresh_pos=_u32(),
+                     refresh_pos=_u32())
     carry = (_f32(k * n), _f32(k * n), _i32(), _f32(n), _f32(n), _i32(n))
     kw = dict(backend="jnp", metric="l2", batch_size=B,
               delta=1.0 / (1000.0 * k * n), sampling="permutation",

@@ -261,6 +261,10 @@ def adaptive_search(
         return go
 
     def body(s: _State) -> _State:
+        with jax.named_scope("search"):
+            return _round(s)
+
+    def _round(s: _State) -> _State:
         if use_perm:
             start = s.rounds * B
             ref_idx = jax.lax.dynamic_slice(perm_idx, (start,), (B,))
@@ -380,7 +384,8 @@ def adaptive_search(
     mu_final = final.sums / jnp.maximum(final.n_used.astype(jnp.float32), 1.0)
 
     def exact_branch(_):
-        mu_exact = exact_fn()
+        with jax.named_scope("exact"):
+            mu_exact = exact_fn()
         mu_sel = jnp.where(final.active, mu_exact, jnp.inf)
         best = jnp.argmin(mu_sel).astype(jnp.int32)
         extra = count_fn(final.active) * jnp.asarray(n_eff).astype(jnp.uint32)

@@ -70,7 +70,6 @@ repairs.  ``reuse="none"`` reproduces the original ledger exactly.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Optional
 
 import jax
@@ -83,8 +82,8 @@ from .engine import (_EXACT_CHUNK, _build_g, _ref_chunks, _swap_batch_stats,
                      _swap_terms, FitContext, cache_read_or_write,
                      counted_dispatch, exact_build_means, exact_swap_means,
                      get_stats_backend, host_read, host_stage, medoid_cache,
-                     observe_tiles, resolve_stats_backend,
-                     resolve_tile_config, stream_columns, total_loss)
+                     observe_tiles, phase, resolve_stats_backend,
+                     resolve_tile_config, span, stream_columns, total_loss)
 from .pic_cache import (PicCache, carry_valid, fresh_positions, make_cache,
                         resolve_batch_cache_rounds, resolve_cache_rounds)
 from .report import BatchFitReport, FitReport
@@ -130,17 +129,18 @@ def _carry_delta(cols: jnp.ndarray, pidx: jnp.ndarray, pw: jnp.ndarray,
 
     Returns (sums', sqsums', n_changed_positions).
     """
-    be = get_stats_backend(backend)
-    width = cols.shape[1]
-    in_prefix = (jnp.arange(width) < n_prefix).astype(jnp.float32)
-    b1, b2, ba = d1o[pidx], d2o[pidx], ao[pidx]
-    c1, c2, ca = d1n[pidx], d2n[pidx], an[pidx]
-    changed = ((b1 != c1) | (b2 != c2) | (ba != ca)).astype(jnp.float32)
-    w = pw * in_prefix * changed
-    s_old, q_old, _ = be.swap_stats_from_d(cols, b1, b2, ba, w, k, None)
-    s_new, q_new, _ = be.swap_stats_from_d(cols, c1, c2, ca, w, k, None)
-    return (sums - s_old + s_new, sqsums - q_old + q_new,
-            jnp.sum(w).astype(jnp.int32))
+    with jax.named_scope("carry"):
+        be = get_stats_backend(backend)
+        width = cols.shape[1]
+        in_prefix = (jnp.arange(width) < n_prefix).astype(jnp.float32)
+        b1, b2, ba = d1o[pidx], d2o[pidx], ao[pidx]
+        c1, c2, ca = d1n[pidx], d2n[pidx], an[pidx]
+        changed = ((b1 != c1) | (b2 != c2) | (ba != ca)).astype(jnp.float32)
+        w = pw * in_prefix * changed
+        s_old, q_old, _ = be.swap_stats_from_d(cols, b1, b2, ba, w, k, None)
+        s_new, q_new, _ = be.swap_stats_from_d(cols, c1, c2, ca, w, k, None)
+        return (sums - s_old + s_new, sqsums - q_old + q_new,
+                jnp.sum(w).astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -180,7 +180,9 @@ def _build_step(data, dnear, med_mask, key, cache, dwarm, perm,
             dxy, aux = cache_read_or_write(
                 be, data, ref_idx, metric=metric, batch_size=B, rnd=rnd,
                 b_eff=jnp.sum(w).astype(jnp.int32), cache=aux)
-            s, q, c = be.build_stats_from_d(dxy, dnear[ref_idx], w, ld(lead))
+            with jax.named_scope("stats"):
+                s, q, c = be.build_stats_from_d(dxy, dnear[ref_idx], w,
+                                                ld(lead))
             return s, q, c, aux
 
         aux_init = cache
@@ -190,22 +192,24 @@ def _build_step(data, dnear, med_mask, key, cache, dwarm, perm,
         def stats_fn(ref_idx, w, lead, rnd):
             # paper App 2.2 cache: warm rounds read precomputed distance
             # columns (same fixed permutation across every search call)
-            return jax.lax.cond(
-                rnd < free_rounds,
-                lambda _: be.build_stats_from_d(
-                    jax.lax.dynamic_slice_in_dim(dwarm, rnd * B, B, 1),
-                    dnear[ref_idx], w, ld(lead)),
-                lambda _: be.build_stats(data, ref_idx, dnear[ref_idx], w,
-                                         ld(lead), metric=metric),
-                None)
+            with jax.named_scope("stats"):
+                return jax.lax.cond(
+                    rnd < free_rounds,
+                    lambda _: be.build_stats_from_d(
+                        jax.lax.dynamic_slice_in_dim(dwarm, rnd * B, B, 1),
+                        dnear[ref_idx], w, ld(lead)),
+                    lambda _: be.build_stats(data, ref_idx, dnear[ref_idx],
+                                             w, ld(lead), metric=metric),
+                    None)
 
         aux_init = None
         free = free_rounds
         free_lo = 0
     else:
         def stats_fn(ref_idx, w, lead, rnd):
-            return be.build_stats(data, ref_idx, dnear[ref_idx], w,
-                                  ld(lead), metric=metric)
+            with jax.named_scope("stats"):
+                return be.build_stats(data, ref_idx, dnear[ref_idx], w,
+                                      ld(lead), metric=metric)
 
         aux_init = None
         free = 0
@@ -301,7 +305,8 @@ def _build_fused(data, subkeys, cache, dwarm, perm, spidx=None, spw=None,
             jnp.zeros((k,), jnp.int32),
             jnp.zeros((k,), jnp.uint32),
             jnp.zeros((k,), jnp.uint32))
-    return jax.lax.fori_loop(0, k, body, init)
+    with jax.named_scope("build"):
+        return jax.lax.fori_loop(0, k, body, init)
 
 
 # ---------------------------------------------------------------------------
@@ -329,8 +334,10 @@ def _swap_search(data, d1, d2, assign, med_mask, key, cache, dwarm, perm,
             dxy, aux = cache_read_or_write(
                 be, data, ref_idx, metric=metric, batch_size=B, rnd=rnd,
                 b_eff=jnp.sum(w).astype(jnp.int32), cache=aux)
-            s, q, c = be.swap_stats_from_d(dxy, d1[ref_idx], d2[ref_idx],
-                                           assign[ref_idx], w, k, ld(lead))
+            with jax.named_scope("stats"):
+                s, q, c = be.swap_stats_from_d(dxy, d1[ref_idx], d2[ref_idx],
+                                               assign[ref_idx], w, k,
+                                               ld(lead))
             return s, q, c, aux
 
         aux_init = cache
@@ -338,25 +345,27 @@ def _swap_search(data, d1, d2, assign, med_mask, key, cache, dwarm, perm,
         free_lo = jnp.maximum(cache.hw - cache.cols.shape[1] // B, 0)
     elif mode == "warm":
         def stats_fn(ref_idx, w, lead, rnd):
-            return jax.lax.cond(
-                rnd < free_rounds,
-                lambda _: be.swap_stats_from_d(
-                    jax.lax.dynamic_slice_in_dim(dwarm, rnd * B, B, 1),
-                    d1[ref_idx], d2[ref_idx], assign[ref_idx], w, k,
-                    ld(lead)),
-                lambda _: be.swap_stats(data, ref_idx, d1[ref_idx],
-                                        d2[ref_idx], assign[ref_idx], w, k,
-                                        ld(lead), metric=metric),
-                None)
+            with jax.named_scope("stats"):
+                return jax.lax.cond(
+                    rnd < free_rounds,
+                    lambda _: be.swap_stats_from_d(
+                        jax.lax.dynamic_slice_in_dim(dwarm, rnd * B, B, 1),
+                        d1[ref_idx], d2[ref_idx], assign[ref_idx], w, k,
+                        ld(lead)),
+                    lambda _: be.swap_stats(data, ref_idx, d1[ref_idx],
+                                            d2[ref_idx], assign[ref_idx], w,
+                                            k, ld(lead), metric=metric),
+                    None)
 
         aux_init = None
         free = free_rounds
         free_lo = 0
     else:
         def stats_fn(ref_idx, w, lead, rnd):
-            return be.swap_stats(data, ref_idx, d1[ref_idx], d2[ref_idx],
-                                 assign[ref_idx], w, k, ld(lead),
-                                 metric=metric)
+            with jax.named_scope("stats"):
+                return be.swap_stats(data, ref_idx, d1[ref_idx], d2[ref_idx],
+                                     assign[ref_idx], w, k, ld(lead),
+                                     metric=metric)
 
         aux_init = None
         free = 0
@@ -411,68 +420,69 @@ def _swap_iter(data, medoids, med_mask, key, cache, dwarm, perm, perm_idx,
     is what makes ``fit_batch`` ≡ loop-of-``fit`` hold bit-for-bit at
     accept margins.  The trailing optional args are the batched lane
     state (see ``_build_step``)."""
-    n = data.shape[0]
-    B = batch_size
-    d1, d2, assign = medoid_cache(data, medoids, metric=metric)
-    n_changed = jnp.int32(0)
-    init_sums = init_sqsums = None
-    init_rounds = 0
-    if carry is not None:
-        # BanditPAM++ PIC: the previous search's per-arm moments stay
-        # valid for every arm whose g is unchanged; _carry_delta repairs
-        # only the contributions of reference points hit by the accepted
-        # swap, from cached columns (zero fresh evals).  Once the ring
-        # has recycled a round the carried prefix is no longer resident,
-        # so the repair is skipped entirely (lax.cond — no wasted
-        # O(n·W·B) pass) and the search starts cold — exact either way,
-        # only the fresh/cached split moves.
-        c_sums, c_sq, c_rounds, d1o, d2o, ao = carry
-        resident = carry_valid(cache, B)
+    with jax.named_scope("swap"):
+        n = data.shape[0]
+        B = batch_size
+        d1, d2, assign = medoid_cache(data, medoids, metric=metric)
+        n_changed = jnp.int32(0)
+        init_sums = init_sqsums = None
+        init_rounds = 0
+        if carry is not None:
+            # BanditPAM++ PIC: the previous search's per-arm moments stay
+            # valid for every arm whose g is unchanged; _carry_delta repairs
+            # only the contributions of reference points hit by the accepted
+            # swap, from cached columns (zero fresh evals).  Once the ring
+            # has recycled a round the carried prefix is no longer resident,
+            # so the repair is skipped entirely (lax.cond — no wasted
+            # O(n·W·B) pass) and the search starts cold — exact either way,
+            # only the fresh/cached split moves.
+            c_sums, c_sq, c_rounds, d1o, d2o, ao = carry
+            resident = carry_valid(cache, B)
 
-        def repair(_):
-            return _carry_delta(cache.cols, perm_idx, perm_w, c_rounds * B,
-                                d1o, d2o, ao, d1, d2, assign, c_sums, c_sq,
-                                k=k, backend=backend)
+            def repair(_):
+                return _carry_delta(cache.cols, perm_idx, perm_w, c_rounds * B,
+                                    d1o, d2o, ao, d1, d2, assign, c_sums, c_sq,
+                                    k=k, backend=backend)
 
-        def cold(_):
-            return (jnp.zeros_like(c_sums), jnp.zeros_like(c_sq),
-                    jnp.int32(0))
+            def cold(_):
+                return (jnp.zeros_like(c_sums), jnp.zeros_like(c_sq),
+                        jnp.int32(0))
 
-        init_sums, init_sqsums, n_changed = jax.lax.cond(
-            resident, repair, cold, None)
-        init_rounds = jnp.where(resident, c_rounds, 0)
-    sr = _swap_search(data, d1, d2, assign, med_mask, key, cache, dwarm,
-                      perm, init_sums, init_sqsums, init_rounds,
-                      s_pidx, s_pw, valid, n_valid, log_term,
-                      backend=backend, metric=metric, batch_size=B,
-                      delta=delta, k=k, sampling=sampling, baseline=baseline,
-                      early_stop=early_stop, mode=mode,
-                      free_rounds=free_rounds)
-    if mode == "pic":
-        cache2 = sr.aux
-        fresh = fresh_positions(cache, cache2)
-    else:
-        cache2 = cache
-        fresh = sr.n_evals
-    m_idx = sr.best // n
-    x_idx = sr.best % n
-    cand = medoids.at[m_idx].set(x_idx)
-    new_loss = total_loss(data, cand, metric=metric, w=valid)
-    # The one accept rule (f32, on device) shared by the single-fit
-    # driver and every fit_batch lane.
-    accept = new_loss < prev_loss - 1e-7 * jnp.maximum(1.0,
-                                                       jnp.abs(prev_loss))
-    new_carry = (sr.sums, sr.sqsums, sr.rounds, d1, d2, assign)
-    # The displaced medoid and the accepted-state mask are produced IN
-    # TRACE so the host driver never does eager index arithmetic on
-    # device arrays (which would be implicit transfers under the
-    # transfer guard); the driver just selects cand/new_mask on accept.
-    old_med = medoids[m_idx]
-    new_mask = med_mask.at[old_med].set(False).at[x_idx].set(True)
-    # fresh is a POSITION count and n_changed a point count under "pic";
-    # the host driver multiplies both by n (uint32-safe).
-    return (sr.best, new_loss, cand, new_mask, old_med, new_carry, cache2,
-            fresh, sr.n_evals_cached, n_changed, sr.used_exact, accept)
+            init_sums, init_sqsums, n_changed = jax.lax.cond(
+                resident, repair, cold, None)
+            init_rounds = jnp.where(resident, c_rounds, 0)
+        sr = _swap_search(data, d1, d2, assign, med_mask, key, cache, dwarm,
+                          perm, init_sums, init_sqsums, init_rounds,
+                          s_pidx, s_pw, valid, n_valid, log_term,
+                          backend=backend, metric=metric, batch_size=B,
+                          delta=delta, k=k, sampling=sampling,
+                          baseline=baseline, early_stop=early_stop,
+                          mode=mode, free_rounds=free_rounds)
+        if mode == "pic":
+            cache2 = sr.aux
+            fresh = fresh_positions(cache, cache2)
+        else:
+            cache2 = cache
+            fresh = sr.n_evals
+        m_idx = sr.best // n
+        x_idx = sr.best % n
+        cand = medoids.at[m_idx].set(x_idx)
+        new_loss = total_loss(data, cand, metric=metric, w=valid)
+        # The one accept rule (f32, on device) shared by the single-fit
+        # driver and every fit_batch lane.
+        accept = new_loss < prev_loss - 1e-7 * jnp.maximum(1.0,
+                                                           jnp.abs(prev_loss))
+        new_carry = (sr.sums, sr.sqsums, sr.rounds, d1, d2, assign)
+        # The displaced medoid and the accepted-state mask are produced IN
+        # TRACE so the host driver never does eager index arithmetic on
+        # device arrays (which would be implicit transfers under the
+        # transfer guard); the driver just selects cand/new_mask on accept.
+        old_med = medoids[m_idx]
+        new_mask = med_mask.at[old_med].set(False).at[x_idx].set(True)
+        # fresh is a POSITION count and n_changed a point count under "pic";
+        # the host driver multiplies both by n (uint32-safe).
+        return (sr.best, new_loss, cand, new_mask, old_med, new_carry, cache2,
+                fresh, sr.n_evals_cached, n_changed, sr.used_exact, accept)
 
 
 # Donations: the PIC ring (arg 4) and the carried swap moments (arg 9)
@@ -531,8 +541,10 @@ def _build_batch(data, subkeys, cache, spidx, spw, valid, n_valid, log_term,
         del dnear  # not needed post-BUILD; keep the lane output lean
         return med_mask, medoids, cc, rounds_a, evals_a, cached_a
 
-    return jax.lax.map(
-        lane, (data, subkeys, cache, spidx, spw, valid, n_valid, log_term))
+    with jax.named_scope("build"):
+        return jax.lax.map(
+            lane, (data, subkeys, cache, spidx, spw, valid, n_valid,
+                   log_term))
 
 
 @functools.partial(jax.jit,
@@ -561,9 +573,10 @@ def _swap_batch(data, medoids, med_mask, subkeys, cache, pidx_c, pw_c,
     ``n_used == 0``), which keeps the while-loop carry a fixed pytree.
 
     Per lane returns (medoids, loss, converged, iters, fresh, cached,
-    n_changed, exact_fallbacks, old[T], new[T], loss[T], accept[T]) —
-    everything the host needs to assemble per-fit FitReports without a
-    mid-phase sync."""
+    n_changed, exact_fallbacks, refresh, old[T], new[T], loss[T],
+    accept[T]) — everything the host needs to assemble per-fit FitReports
+    without a mid-phase sync; ``refresh`` is the ring's ``refresh_pos``
+    over the whole fit."""
     n = data.shape[1]
     kn = k * n
     T = max_swaps
@@ -613,12 +626,14 @@ def _swap_batch(data, medoids, med_mask, subkeys, cache, pidx_c, pw_c,
                jnp.zeros((T,), jnp.int32), jnp.zeros((T,), jnp.int32),
                jnp.zeros((T,), jnp.float32), jnp.zeros((T,), jnp.bool_))
         stf = jax.lax.while_loop(cond, body, st0)
+        refresh = stf[6].refresh_pos if pic else jnp.uint32(0)
         return (stf[2], stf[4], stf[1], stf[0], stf[7], stf[8], stf[9],
-                stf[10], stf[11], stf[12], stf[13], stf[14])
+                stf[10], refresh, stf[11], stf[12], stf[13], stf[14])
 
-    return jax.lax.map(lane, (data, medoids, med_mask, subkeys, cache,
-                              pidx_c, pw_c, spidx, spw, valid, n_valid,
-                              log_term))
+    with jax.named_scope("swap"):
+        return jax.lax.map(lane, (data, medoids, med_mask, subkeys, cache,
+                                  pidx_c, pw_c, spidx, spw, valid, n_valid,
+                                  log_term))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "T"))
@@ -746,7 +761,8 @@ class BanditPAM:
                                           metric=self.metric)
                     cache = PicCache(
                         cache.cols.at[:, :warm * B].set(cols),
-                        jnp.int32(warm), jnp.uint32(warm * B))
+                        jnp.int32(warm), jnp.uint32(warm * B),
+                        cache.refresh_pos)
                     res.evals_by_phase["cache_warm"] = n * warm * B
             return FitContext(mode="pic", backend=backend, perm=perm,
                               perm_idx=perm_idx, perm_w=perm_w, cache=cache)
@@ -853,21 +869,22 @@ class BanditPAM:
             _swap_iter_jit if self.fused else self._swap_iter_stepped,
             res.dispatches_by_phase, "swap")
         for _ in range(self.max_swaps):
-            key, sub = jax.random.split(key)
-            (best, new_loss_d, cand, new_mask, old_med, new_carry, cache,
-             fresh, cached, n_changed, used_exact, accept) = step(
-                 data, medoids, med_mask, sub, ctx.cache, ctx.dwarm,
-                 ctx.perm, ctx.perm_idx, ctx.perm_w, carry,
-                 prev_loss_d, **kw)
-            ctx.cache = cache
-            # ONE explicit host read per iteration: every ledger counter,
-            # the displaced medoid and the accept bit come back in a
-            # single device_get, so the loop is one dispatch + one
-            # sanctioned read under the transfer guard.
-            (best_h, new_loss_h, old_h, fresh_h, cached_h, n_changed_h,
-             used_exact_h, accept_h) = host_read(
-                 (best, new_loss_d, old_med, fresh, cached, n_changed,
-                  used_exact, accept))
+            with span("swap.iter"):
+                key, sub = jax.random.split(key)
+                (best, new_loss_d, cand, new_mask, old_med, new_carry, cache,
+                 fresh, cached, n_changed, used_exact, accept) = step(
+                     data, medoids, med_mask, sub, ctx.cache, ctx.dwarm,
+                     ctx.perm, ctx.perm_idx, ctx.perm_w, carry,
+                     prev_loss_d, **kw)
+                ctx.cache = cache
+                # ONE explicit host read per iteration: every ledger
+                # counter, the displaced medoid and the accept bit come
+                # back in a single device_get, so the loop is one
+                # dispatch + one sanctioned read under the transfer guard.
+                (best_h, new_loss_h, old_h, fresh_h, cached_h, n_changed_h,
+                 used_exact_h, accept_h) = host_read(
+                     (best, new_loss_d, old_med, fresh, cached, n_changed,
+                      used_exact, accept))
             # Under "pic", fresh counts POSITIONS and n_changed counts
             # repaired points; the n· multiplies run on host ints so the
             # ledger cannot wrap at large n.
@@ -953,6 +970,7 @@ class BanditPAM:
                 int(sr.used_exact), accept)
 
     # -- public ----------------------------------------------------------
+    @span("fit")
     def fit(self, data, warm_start=None) -> FitResult:
         """Fit medoids; ``warm_start`` (optional ``[k]`` indices) skips
         BUILD and seeds SWAP from the given medoids.
@@ -987,21 +1005,25 @@ class BanditPAM:
                     raise ValueError(f"warm_start indices out of range "
                                      f"[0, {n})")
                 ctx.warm_medoids = jnp.asarray(ws, jnp.int32)
-        t0 = time.perf_counter()
-        if ctx.warm_medoids is not None:
-            medoids = ctx.warm_medoids
-            with host_stage("warm-start staging: medoid mask upload"):
-                med_mask = jnp.zeros((n,), jnp.bool_).at[medoids].set(True)
-            res.evals_by_phase["build"] = 0
-        else:
-            medoids, med_mask, key = self._build(data, key, ctx, res)
-        jax.block_until_ready(medoids)
-        res.wall_by_phase["build"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        medoids, loss, converged = self._swap(data, medoids, med_mask, key,
-                                              ctx, res)
-        res.wall_by_phase["swap"] = time.perf_counter() - t0
-        res.medoids = np.asarray(host_read(medoids))
+        with phase("build", res.wall_by_phase):
+            if ctx.warm_medoids is not None:
+                medoids = ctx.warm_medoids
+                with host_stage("warm-start staging: medoid mask upload"):
+                    med_mask = jnp.zeros((n,), jnp.bool_).at[medoids].set(
+                        True)
+                res.evals_by_phase["build"] = 0
+            else:
+                medoids, med_mask, key = self._build(data, key, ctx, res)
+            jax.block_until_ready(medoids)
+        with phase("swap", res.wall_by_phase):
+            medoids, loss, converged = self._swap(data, medoids, med_mask,
+                                                  key, ctx, res)
+        # The ring's evicted-round replays: a position count, multiplied
+        # by n on host ints (no uint32 wrap).
+        refresh = ctx.cache.refresh_pos if ctx.mode == "pic" else 0
+        medoids_h, refresh_h = host_read((medoids, refresh))
+        res.medoids = np.asarray(medoids_h)
+        res.refresh_evals = n * int(refresh_h)
         res.loss = loss
         res.n_swaps = len(res.swap_history)
         res.converged = converged
@@ -1018,6 +1040,7 @@ class BanditPAM:
                       res.wall_by_phase, backend=backend)
         return res
 
+    @span("fit")
     def fit_batch(self, datasets, seeds=None) -> BatchFitReport:
         """Fit a batch of INDEPENDENT datasets in one dispatch per phase.
 
@@ -1163,7 +1186,9 @@ class BanditPAM:
                 cache=(PicCache(
                     cols=jnp.zeros((bf, n_max, wcap * B), jnp.float32),
                     hw=jnp.zeros((bf,), jnp.int32),
-                    fresh_pos=jnp.zeros((bf,), jnp.uint32)) if pic else None),
+                    fresh_pos=jnp.zeros((bf,), jnp.uint32),
+                    refresh_pos=jnp.zeros((bf,), jnp.uint32)) if pic
+                       else None),
                 batch=bf, valid=jnp.asarray(valid),
                 n_valid=jnp.asarray(ns, jnp.int32),
                 log_build=jnp.asarray(log_b), log_swap=jnp.asarray(log_s),
@@ -1177,26 +1202,26 @@ class BanditPAM:
                   delta=self.delta, sampling=self.sampling,
                   baseline=self.baseline, k=k, mode=ctx.mode, free_rounds=0)
 
-        t0 = time.perf_counter()
-        bphase = counted_dispatch(_build_batch, disp, "build")
-        (med_mask, medoids, cache, rounds_a, evals_a, cached_a) = bphase(
-            dataj, bkeys_j, ctx.cache, ctx.spidx_build, ctx.spw,
-            ctx.valid, ctx.n_valid, ctx.log_build, **kw)
-        jax.block_until_ready(medoids)
-        ctx.cache = cache
-        wall = {"build": time.perf_counter() - t0}
+        wall: dict = {}
+        with phase("build", wall):
+            bphase = counted_dispatch(_build_batch, disp, "build")
+            (med_mask, medoids, cache, rounds_a, evals_a, cached_a) = bphase(
+                dataj, bkeys_j, ctx.cache, ctx.spidx_build, ctx.spw,
+                ctx.valid, ctx.n_valid, ctx.log_build, **kw)
+            jax.block_until_ready(medoids)
+            ctx.cache = cache
 
         kw.pop("sampling")
-        t0 = time.perf_counter()
-        sphase = counted_dispatch(_swap_batch, disp, "swap")
-        (meds_f, loss_f, conv, iters, fresh_s, cached_s, nchg_s, exact_s,
-         old_a, new_a, loss_a, acc_a) = sphase(
-             dataj, medoids, med_mask, skeys_j, ctx.cache,
-             ctx.perm_idx, ctx.perm_w, ctx.spidx_swap, ctx.spw, ctx.valid,
-             ctx.n_valid, ctx.log_swap, sampling=self.sampling,
-             early_stop=self.swap_early_stop, max_swaps=T, **kw)
-        jax.block_until_ready(loss_f)
-        wall["swap"] = time.perf_counter() - t0
+        with phase("swap", wall):
+            sphase = counted_dispatch(_swap_batch, disp, "swap")
+            (meds_f, loss_f, conv, iters, fresh_s, cached_s, nchg_s,
+             exact_s, refresh_s, old_a, new_a, loss_a, acc_a) = sphase(
+                 dataj, medoids, med_mask, skeys_j, ctx.cache,
+                 ctx.perm_idx, ctx.perm_w, ctx.spidx_swap, ctx.spw,
+                 ctx.valid, ctx.n_valid, ctx.log_swap,
+                 sampling=self.sampling, early_stop=self.swap_early_stop,
+                 max_swaps=T, **kw)
+            jax.block_until_ready(loss_f)
 
         # -- per-fit ledger assembly (host ints: no uint32 wrap) ---------
         # ONE explicit device→host read for the whole batch: every
@@ -1204,11 +1229,11 @@ class BanditPAM:
         # the batch driver mirrors the single-fit guard contract (one
         # dispatch per phase + sanctioned reads only).
         (meds_np, loss_np, conv_np, iters_np, rounds_np, bev_np, bca_np,
-         fresh_np, cached_np, nchg_np, exact_np, old_np, new_np, la_np,
-         acc_np) = host_read(
+         fresh_np, cached_np, nchg_np, exact_np, refresh_np, old_np, new_np,
+         la_np, acc_np) = host_read(
             (meds_f, loss_f, conv, iters, rounds_a, evals_a, cached_a,
-             fresh_s, cached_s, nchg_s, exact_s, old_a, new_a, loss_a,
-             acc_a))
+             fresh_s, cached_s, nchg_s, exact_s, refresh_s, old_a, new_a,
+             loss_a, acc_a))
         iters_np = np.asarray(iters_np, np.int64)
         rounds_np = np.asarray(rounds_np, np.int64)
         bev_np = np.asarray(bev_np, np.int64)
@@ -1234,6 +1259,7 @@ class BanditPAM:
             if pic:
                 res.evals_by_phase["swap_cached"] = (
                     int(cached_np[i]) + n_i * int(nchg_np[i]))
+                res.refresh_evals = n_i * int(refresh_np[i])
             res.swap_exact_fallbacks = int(exact_np[i])
             for t in range(it):
                 if acc_np[i, t]:

@@ -67,7 +67,6 @@ spans every local device (``default_mesh``).
 
 from __future__ import annotations
 
-import time
 from typing import Optional, Tuple
 
 import jax
@@ -77,8 +76,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .adaptive import adaptive_search
 from .engine import (counted_dispatch, exact_build_means, exact_swap_means,
-                     get_stats_backend, medoid_cache, resolve_stats_backend,
-                     total_loss)
+                     get_stats_backend, medoid_cache, phase,
+                     resolve_stats_backend, span, total_loss)
 from .pic_cache import (PicCache, cache_advance, carry_valid,
                         fresh_positions, resolve_cache_rounds,
                         shard_slot_read_write)
@@ -258,8 +257,10 @@ class DistributedBanditPAM:
             idx = _shard_draws(rkey, ax, v, b_loc)
             gidx = jnp.minimum(ax * n_loc + idx, n - 1)
             w = jnp.ones((b_loc,), jnp.float32)
-            dxy = be.pairwise(data_f, data_l[idx], metric=metric)  # [n, b_loc]
-            s, q, c = be.build_stats_from_d(dxy, dnear_f[gidx], w, lead)
+            with jax.named_scope("fresh"):
+                dxy = be.pairwise(data_f, data_l[idx], metric=metric)
+            with jax.named_scope("stats"):
+                s, q, c = be.build_stats_from_d(dxy, dnear_f[gidx], w, lead)
             return (jax.lax.psum(s * cs, self.daxes),
                     jax.lax.psum(q * (cs * cs), self.daxes),
                     jax.lax.psum(c * (cs * cs), self.daxes))
@@ -285,9 +286,11 @@ class DistributedBanditPAM:
             idx = _shard_draws(rkey, ax, v, b_loc)
             gidx = jnp.minimum(ax * n_loc + idx, n - 1)
             w = jnp.ones((b_loc,), jnp.float32)
-            dxy = be.pairwise(data_f, data_l[idx], metric=metric)
-            s, q, c = be.swap_stats_from_d(dxy, d1_f[gidx], d2_f[gidx],
-                                           a_f[gidx], w, k, lead)
+            with jax.named_scope("fresh"):
+                dxy = be.pairwise(data_f, data_l[idx], metric=metric)
+            with jax.named_scope("stats"):
+                s, q, c = be.swap_stats_from_d(dxy, d1_f[gidx], d2_f[gidx],
+                                               a_f[gidx], w, k, lead)
             return (jax.lax.psum(s * cs, self.daxes),
                     jax.lax.psum(q * (cs * cs), self.daxes),
                     jax.lax.psum(c * (cs * cs), self.daxes))
@@ -354,7 +357,8 @@ class DistributedBanditPAM:
         cache = PicCache(
             cols=jax.device_put(
                 jnp.zeros((n, S * W * b_loc), jnp.float32), sh_cols),
-            hw=jnp.int32(0), fresh_pos=jnp.uint32(0))
+            hw=jnp.int32(0), fresh_pos=jnp.uint32(0),
+            refresh_pos=jnp.uint32(0))
         return (lperm_d, lw_d, to_global(gidx.astype(np.int32)),
                 to_global(lw), cache, W)
 
@@ -377,7 +381,8 @@ class DistributedBanditPAM:
             dxy, cols = shard_slot_read_write(
                 cols, rnd, hw, b_loc,
                 lambda: be.pairwise(data_f, data_l[lidx], metric=metric))
-            s, q, c = be.build_stats_from_d(dxy, dnear_f[gidx], w, lead)
+            with jax.named_scope("stats"):
+                s, q, c = be.build_stats_from_d(dxy, dnear_f[gidx], w, lead)
             return (jax.lax.psum(s, daxes), jax.lax.psum(q, daxes),
                     jax.lax.psum(c, daxes), cols)
 
@@ -406,8 +411,9 @@ class DistributedBanditPAM:
             dxy, cols = shard_slot_read_write(
                 cols, rnd, hw, b_loc,
                 lambda: be.pairwise(data_f, data_l[lidx], metric=metric))
-            s, q, c = be.swap_stats_from_d(dxy, d1_f[gidx], d2_f[gidx],
-                                           a_f[gidx], w, k, lead)
+            with jax.named_scope("stats"):
+                s, q, c = be.swap_stats_from_d(dxy, d1_f[gidx], d2_f[gidx],
+                                               a_f[gidx], w, k, lead)
             return (jax.lax.psum(s, daxes), jax.lax.psum(q, daxes),
                     jax.lax.psum(c, daxes), cols)
 
@@ -431,24 +437,25 @@ class DistributedBanditPAM:
         daxes = self.daxes
 
         def local(cols, lperm, lw, n_prefix_loc, d1o, d2o, ao, d1n, d2n, an):
-            ax = axfn()
-            pidx = lperm[0][:width_loc]
-            pw = lw[0][:width_loc]
-            gidx = jnp.minimum(ax * n_loc + pidx, n - 1)
-            in_prefix = (jnp.arange(width_loc) < n_prefix_loc).astype(
-                jnp.float32)
-            b1, b2, ba = d1o[gidx], d2o[gidx], ao[gidx]
-            c1, c2, ca = d1n[gidx], d2n[gidx], an[gidx]
-            changed = ((b1 != c1) | (b2 != c2) | (ba != ca)).astype(
-                jnp.float32)
-            w = pw * in_prefix * changed
-            s_old, q_old, _ = be.swap_stats_from_d(cols, b1, b2, ba, w, k,
-                                                   None)
-            s_new, q_new, _ = be.swap_stats_from_d(cols, c1, c2, ca, w, k,
-                                                   None)
-            return (jax.lax.psum(s_new - s_old, daxes),
-                    jax.lax.psum(q_new - q_old, daxes),
-                    jax.lax.psum(jnp.sum(w), daxes))
+            with jax.named_scope("carry"):
+                ax = axfn()
+                pidx = lperm[0][:width_loc]
+                pw = lw[0][:width_loc]
+                gidx = jnp.minimum(ax * n_loc + pidx, n - 1)
+                in_prefix = (jnp.arange(width_loc) < n_prefix_loc).astype(
+                    jnp.float32)
+                b1, b2, ba = d1o[gidx], d2o[gidx], ao[gidx]
+                c1, c2, ca = d1n[gidx], d2n[gidx], an[gidx]
+                changed = ((b1 != c1) | (b2 != c2) | (ba != ca)).astype(
+                    jnp.float32)
+                w = pw * in_prefix * changed
+                s_old, q_old, _ = be.swap_stats_from_d(cols, b1, b2, ba, w,
+                                                       k, None)
+                s_new, q_new, _ = be.swap_stats_from_d(cols, c1, c2, ca, w,
+                                                       k, None)
+                return (jax.lax.psum(s_new - s_old, daxes),
+                        jax.lax.psum(q_new - q_old, daxes),
+                        jax.lax.psum(jnp.sum(w), daxes))
 
         return _shard_map(local, self.mesh,
                           in_specs=(P(None, self.daxes),
@@ -542,7 +549,8 @@ class DistributedBanditPAM:
                     jnp.zeros((k,), jnp.int32),
                     jnp.zeros((k,), jnp.uint32),
                     jnp.zeros((k,), jnp.uint32))
-            return jax.lax.fori_loop(0, k, body, init)
+            with jax.named_scope("build"):
+                return jax.lax.fori_loop(0, k, body, init)
 
         return build_phase
 
@@ -568,91 +576,94 @@ class DistributedBanditPAM:
         def swap_iter(data, data_sh, medoids, med_mask, phase_key,
                       search_key, lperm, lw, perm_idx_g, perm_w_g, cache,
                       carry):
-            d1, d2, assign = medoid_cache(data, medoids, metric=metric)
-            n_changed = jnp.int32(0)
-            init_sums = init_sqsums = None
-            init_rounds = 0
-            if mode == "pic" and carry is not None:
-                # Repair the carried per-arm moments against the new
-                # medoid cache from the sharded PIC columns (zero fresh
-                # evals); once ring recycling has evicted part of the
-                # carried prefix the repair is skipped entirely
-                # (lax.cond) and the search starts cold.
-                c_sums, c_sq, c_rounds, d1o, d2o, ao = carry
-                valid = carry_valid(cache, rounds_cap=W)
+            with jax.named_scope("swap"):
+                d1, d2, assign = medoid_cache(data, medoids, metric=metric)
+                n_changed = jnp.int32(0)
+                init_sums = init_sqsums = None
+                init_rounds = 0
+                if mode == "pic" and carry is not None:
+                    # Repair the carried per-arm moments against the new
+                    # medoid cache from the sharded PIC columns (zero fresh
+                    # evals); once ring recycling has evicted part of the
+                    # carried prefix the repair is skipped entirely
+                    # (lax.cond) and the search starts cold.
+                    c_sums, c_sq, c_rounds, d1o, d2o, ao = carry
+                    valid = carry_valid(cache, rounds_cap=W)
 
-                def repair(_):
-                    ds, dq, nch = carry_smap(
-                        cache.cols, lperm, lw, c_rounds * b_loc,
-                        d1o, d2o, ao, d1, d2, assign)
-                    return c_sums + ds, c_sq + dq, nch.astype(jnp.int32)
+                    def repair(_):
+                        ds, dq, nch = carry_smap(
+                            cache.cols, lperm, lw, c_rounds * b_loc,
+                            d1o, d2o, ao, d1, d2, assign)
+                        return c_sums + ds, c_sq + dq, nch.astype(jnp.int32)
 
-                def cold(_):
-                    return (jnp.zeros_like(c_sums), jnp.zeros_like(c_sq),
-                            jnp.int32(0))
+                    def cold(_):
+                        return (jnp.zeros_like(c_sums), jnp.zeros_like(c_sq),
+                                jnp.int32(0))
 
-                init_sums, init_sqsums, n_changed = jax.lax.cond(
-                    valid, repair, cold, None)
-                init_rounds = jnp.where(valid, c_rounds, 0)
+                    init_sums, init_sqsums, n_changed = jax.lax.cond(
+                        valid, repair, cold, None)
+                    init_rounds = jnp.where(valid, c_rounds, 0)
 
-            active0 = jnp.tile(jnp.logical_not(med_mask)[None, :],
-                               (k, 1)).reshape(-1)
+                active0 = jnp.tile(jnp.logical_not(med_mask)[None, :],
+                                   (k, 1)).reshape(-1)
 
-            def count_fn(active):
-                # FastPAM1: one distance per (x, y) serves all k arms (·, x).
-                any_x = jnp.any(active.reshape(k, n), axis=0)
-                return jnp.sum(any_x.astype(jnp.uint32))
+                def count_fn(active):
+                    # FastPAM1: one distance per (x, y) serves all k arms
+                    # (·, x).
+                    any_x = jnp.any(active.reshape(k, n), axis=0)
+                    return jnp.sum(any_x.astype(jnp.uint32))
 
-            def exact_fn():
-                return exact_means(data, d1, d2, assign)
+                def exact_fn():
+                    return exact_means(data, d1, d2, assign)
 
-            if mode == "pic":
-                def stats_fn(ref_idx, w, lead, rnd, aux):
-                    s, q, cr, cols = smap(data, data_sh, d1, d2, assign,
-                                          lperm, lw, aux.cols, rnd, aux.hw,
-                                          lead)
-                    return s, q, cr, cache_advance(
-                        aux, cols, rnd, jnp.sum(w), W)
+                if mode == "pic":
+                    def stats_fn(ref_idx, w, lead, rnd, aux):
+                        s, q, cr, cols = smap(data, data_sh, d1, d2, assign,
+                                              lperm, lw, aux.cols, rnd, aux.hw,
+                                              lead)
+                        return s, q, cr, cache_advance(
+                            aux, cols, rnd, jnp.sum(w), W)
 
-                sr = adaptive_search(
-                    search_key, stats_fn=stats_fn, exact_fn=exact_fn,
-                    n_arms=k * n, n_ref=n, batch_size=B, delta=delta,
-                    active_init=active0, count_fn=count_fn,
-                    sampling="permutation", baseline="leader",
-                    perm_idx=perm_idx_g, perm_w=perm_w_g,
-                    free_rounds=cache.hw,
-                    free_lo=jnp.maximum(cache.hw - W, 0),
-                    init_sums=init_sums, init_sqsums=init_sqsums,
-                    init_rounds=init_rounds, aux_init=cache)
-                cache2 = sr.aux
-                fresh = fresh_positions(cache, cache2)
-                cached = sr.n_evals_cached
-            else:
-                def stats_fn(ref_idx, w, lead, rnd):
-                    return smap(data, data_sh, d1, d2, assign,
-                                _round_key(phase_key, rnd), lead)
+                    sr = adaptive_search(
+                        search_key, stats_fn=stats_fn, exact_fn=exact_fn,
+                        n_arms=k * n, n_ref=n, batch_size=B, delta=delta,
+                        active_init=active0, count_fn=count_fn,
+                        sampling="permutation", baseline="leader",
+                        perm_idx=perm_idx_g, perm_w=perm_w_g,
+                        free_rounds=cache.hw,
+                        free_lo=jnp.maximum(cache.hw - W, 0),
+                        init_sums=init_sums, init_sqsums=init_sqsums,
+                        init_rounds=init_rounds, aux_init=cache)
+                    cache2 = sr.aux
+                    fresh = fresh_positions(cache, cache2)
+                    cached = sr.n_evals_cached
+                else:
+                    def stats_fn(ref_idx, w, lead, rnd):
+                        return smap(data, data_sh, d1, d2, assign,
+                                    _round_key(phase_key, rnd), lead)
 
-                sr = adaptive_search(
-                    search_key, stats_fn=stats_fn, exact_fn=exact_fn,
-                    n_arms=k * n, n_ref=n, batch_size=B, delta=delta,
-                    active_init=active0, count_fn=count_fn,
-                    sampling="replacement", baseline="leader")
-                cache2 = cache
-                fresh = sr.n_evals
-                cached = sr.n_evals_cached
-            m_idx = sr.best // n
-            x_idx = sr.best % n
-            cand = medoids.at[m_idx].set(x_idx)
-            new_loss = total_loss(data, cand, metric=metric)
-            new_carry = (sr.sums, sr.sqsums, sr.rounds, d1, d2, assign)
-            # fresh is a POSITION count and n_changed a point count under
-            # "pic"; the host multiplies both by n (uint32-safe).
-            return (sr.best, new_loss, cand, new_carry, cache2, fresh,
-                    cached, n_changed, sr.used_exact)
+                    sr = adaptive_search(
+                        search_key, stats_fn=stats_fn, exact_fn=exact_fn,
+                        n_arms=k * n, n_ref=n, batch_size=B, delta=delta,
+                        active_init=active0, count_fn=count_fn,
+                        sampling="replacement", baseline="leader")
+                    cache2 = cache
+                    fresh = sr.n_evals
+                    cached = sr.n_evals_cached
+                m_idx = sr.best // n
+                x_idx = sr.best % n
+                cand = medoids.at[m_idx].set(x_idx)
+                new_loss = total_loss(data, cand, metric=metric)
+                new_carry = (sr.sums, sr.sqsums, sr.rounds, d1, d2, assign)
+                # fresh is a POSITION count and n_changed a point count under
+                # "pic"; the host multiplies both by n (uint32-safe).
+                return (sr.best, new_loss, cand, new_carry, cache2, fresh,
+                        cached, n_changed, sr.used_exact)
 
         return swap_iter
 
     # -- fit --------------------------------------------------------------
+    @span("fit")
     def fit(self, data) -> FitReport:
         data = jnp.asarray(data, jnp.float32)
         n = data.shape[0]
@@ -677,82 +688,87 @@ class DistributedBanditPAM:
         # BUILD — the whole phase is ONE jit dispatch (fori_loop over the
         # k selections, shard_map inside); the host reads only the final
         # medoids and ledger arrays.
-        t0 = time.perf_counter()
-        delta = self.delta if self.delta is not None else 1.0 / (1000.0 * n)
-        ck = self._step_key("build", backend, n, delta, W)
-        if ck not in _STEP_CACHE:
-            _STEP_CACHE[ck] = self._make_build_phase(be, n, delta, W)
-        # dispatches_by_phase is MEASURED at the call sites (one count per
-        # compiled-phase call) — the bench assertion guards real behavior.
-        build_phase = counted_dispatch(_STEP_CACHE[ck],
-                                       res.dispatches_by_phase, "build")
-        # One subkey per medoid selection, split exactly as the historical
-        # per-selection host loop did, so trajectories are seed-compatible.
-        subs = []
-        for _ in range(self.k):
-            key, sub = jax.random.split(key)
-            subs.append(sub)
-        (dnear, med_mask, med, cache, rounds_a, evals_a,
-         cached_a) = build_phase(
-            data, data_sh, jax.random.PRNGKey(self.seed ^ _BUILD_TAG),
-            jnp.stack(subs), lperm, lw, pidx_g, pw_g, cache)
-        res.build_rounds.extend(
-            int(r) for r in np.asarray(rounds_a, np.int64))
-        # Under "pic" the per-step entries are fresh POSITION counts; the
-        # n· multiply happens here on host ints (no uint32 wrap).
-        res.evals_by_phase["build"] = (
-            (n if pic else 1) * int(np.asarray(evals_a, np.int64).sum())
-            + n * self.k)
-        if pic:
-            res.evals_by_phase["build_cached"] = int(
-                np.asarray(cached_a, np.int64).sum())
-        jax.block_until_ready(dnear)
-        res.wall_by_phase["build"] = time.perf_counter() - t0
+        with phase("build", res.wall_by_phase):
+            delta = (self.delta if self.delta is not None
+                     else 1.0 / (1000.0 * n))
+            ck = self._step_key("build", backend, n, delta, W)
+            if ck not in _STEP_CACHE:
+                _STEP_CACHE[ck] = self._make_build_phase(be, n, delta, W)
+            # dispatches_by_phase is MEASURED at the call sites (one count
+            # per compiled-phase call) — the bench assertion guards real
+            # behavior.
+            build_phase = counted_dispatch(_STEP_CACHE[ck],
+                                           res.dispatches_by_phase, "build")
+            # One subkey per medoid selection, split exactly as the
+            # historical per-selection host loop did, so trajectories are
+            # seed-compatible.
+            subs = []
+            for _ in range(self.k):
+                key, sub = jax.random.split(key)
+                subs.append(sub)
+            (dnear, med_mask, med, cache, rounds_a, evals_a,
+             cached_a) = build_phase(
+                data, data_sh, jax.random.PRNGKey(self.seed ^ _BUILD_TAG),
+                jnp.stack(subs), lperm, lw, pidx_g, pw_g, cache)
+            res.build_rounds.extend(
+                int(r) for r in np.asarray(rounds_a, np.int64))
+            # Under "pic" the per-step entries are fresh POSITION counts;
+            # the n· multiply happens here on host ints (no uint32 wrap).
+            res.evals_by_phase["build"] = (
+                (n if pic else 1) * int(np.asarray(evals_a, np.int64).sum())
+                + n * self.k)
+            if pic:
+                res.evals_by_phase["build_cached"] = int(
+                    np.asarray(cached_a, np.int64).sum())
+            jax.block_until_ready(dnear)
 
         # SWAP — the fused per-iteration step; host reads accept/converge.
-        t0 = time.perf_counter()
-        delta_s = (self.delta if self.delta is not None
-                   else 1.0 / (1000.0 * self.k * n))
-        ck = self._step_key("swap", backend, n, delta_s, W)
-        if ck not in _STEP_CACHE:
-            _STEP_CACHE[ck] = self._make_swap_iter(be, n, delta_s, W)
-        swap_iter = counted_dispatch(_STEP_CACHE[ck],
-                                     res.dispatches_by_phase, "swap")
-        loss = float(total_loss(data, med, metric=self.metric))
-        swap_evals = 0
-        swap_cached = 0
-        converged = False
-        carry = None
-        for t in range(self.max_swaps):
-            key, sub = jax.random.split(key)
-            (best, new_loss_d, cand, new_carry, cache, fresh, cached,
-             n_changed, used_exact) = swap_iter(
-                data, data_sh, med, med_mask,
-                _phase_key(self.seed, _SWAP_TAG, t), sub,
-                lperm, lw, pidx_g, pw_g, cache, carry)
-            # cache refresh (n·k) + candidate loss (n·k) + bandit rounds;
-            # under "pic" fresh/n_changed are position/point counts and
-            # the n· multiplies run on host ints (no uint32 wrap).
-            swap_evals += 2 * n * self.k + (n if pic else 1) * int(fresh)
-            swap_cached += int(cached) + n * int(n_changed)
-            res.swap_exact_fallbacks += int(used_exact)
+        with phase("swap", res.wall_by_phase):
+            delta_s = (self.delta if self.delta is not None
+                       else 1.0 / (1000.0 * self.k * n))
+            ck = self._step_key("swap", backend, n, delta_s, W)
+            if ck not in _STEP_CACHE:
+                _STEP_CACHE[ck] = self._make_swap_iter(be, n, delta_s, W)
+            swap_iter = counted_dispatch(_STEP_CACHE[ck],
+                                         res.dispatches_by_phase, "swap")
+            loss = float(total_loss(data, med, metric=self.metric))
+            swap_evals = 0
+            swap_cached = 0
+            converged = False
+            carry = None
+            for t in range(self.max_swaps):
+                with span("swap.iter"):
+                    key, sub = jax.random.split(key)
+                    (best, new_loss_d, cand, new_carry, cache, fresh, cached,
+                     n_changed, used_exact) = swap_iter(
+                        data, data_sh, med, med_mask,
+                        _phase_key(self.seed, _SWAP_TAG, t), sub,
+                        lperm, lw, pidx_g, pw_g, cache, carry)
+                    # cache refresh (n·k) + candidate loss (n·k) + bandit
+                    # rounds; under "pic" fresh/n_changed are position/point
+                    # counts and the n· multiplies run on host ints (no
+                    # uint32 wrap).
+                    swap_evals += (2 * n * self.k
+                                   + (n if pic else 1) * int(fresh))
+                    swap_cached += int(cached) + n * int(n_changed)
+                    res.swap_exact_fallbacks += int(used_exact)
+                    if pic:
+                        carry = new_carry
+                    new_loss = float(new_loss_d)
+                    if new_loss < loss - 1e-7 * max(1.0, abs(loss)):
+                        m_idx, x_idx = divmod(int(best), n)
+                        old = int(med[m_idx])
+                        med = cand
+                        med_mask = med_mask.at[old].set(False).at[
+                            x_idx].set(True)
+                        res.swap_history.append((old, x_idx, new_loss))
+                        loss = new_loss
+                    else:
+                        converged = True
+                        break
+            res.evals_by_phase["swap"] = swap_evals
             if pic:
-                carry = new_carry
-            new_loss = float(new_loss_d)
-            if new_loss < loss - 1e-7 * max(1.0, abs(loss)):
-                m_idx, x_idx = divmod(int(best), n)
-                old = int(med[m_idx])
-                med = cand
-                med_mask = med_mask.at[old].set(False).at[x_idx].set(True)
-                res.swap_history.append((old, x_idx, new_loss))
-                loss = new_loss
-            else:
-                converged = True
-                break
-        res.evals_by_phase["swap"] = swap_evals
-        if pic:
-            res.evals_by_phase["swap_cached"] = swap_cached
-        res.wall_by_phase["swap"] = time.perf_counter() - t0
+                res.evals_by_phase["swap_cached"] = swap_cached
 
         res.medoids = np.asarray(med, np.int64)
         res.loss = loss
@@ -762,6 +778,8 @@ class DistributedBanditPAM:
                                  if not ph.endswith("_cached"))
         res.cached_evals = sum(v for ph, v in res.evals_by_phase.items()
                                if ph.endswith("_cached"))
+        if pic:
+            res.refresh_evals = n * int(cache.refresh_pos)
         return res
 
 

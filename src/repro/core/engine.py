@@ -46,6 +46,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -135,7 +136,8 @@ def medoid_cache(data: jnp.ndarray, medoids: jnp.ndarray, *, metric: str,
     # tracecheck: ignore[TRC001] -- `tile` is in static_argnames: a host int
     # at trace time, never a traced value.
     t = _EXACT_CHUNK if tile is None else int(tile)
-    return _stream_top2_jnp(data, data[medoids], metric=metric, tile=t)
+    with jax.named_scope("top2"):
+        return _stream_top2_jnp(data, data[medoids], metric=metric, tile=t)
 
 
 @functools.partial(jax.jit, static_argnames=("metric", "tile"))
@@ -152,17 +154,18 @@ def total_loss(data: jnp.ndarray, medoids: jnp.ndarray, *, metric: str,
     # at trace time, never a traced value.
     t = _EXACT_CHUNK if tile is None else int(tile)
     n = data.shape[0]
-    med = data[medoids]
     fn = get_metric(metric)
-    if n <= t:
-        dmin = jnp.min(fn(data, med), axis=1)
-    else:
-        dmin = _stream_rows(data, t,
-                            lambda xt: jnp.min(fn(xt, med), axis=1),
-                            jnp.zeros((n,), jnp.float32))
-    if w is None:
-        return jnp.sum(dmin)
-    return jnp.sum(jnp.where(w, dmin, 0.0))
+    with jax.named_scope("top2"):
+        med = data[medoids]
+        if n <= t:
+            dmin = jnp.min(fn(data, med), axis=1)
+        else:
+            dmin = _stream_rows(data, t,
+                                lambda xt: jnp.min(fn(xt, med), axis=1),
+                                jnp.zeros((n,), jnp.float32))
+        if w is None:
+            return jnp.sum(dmin)
+        return jnp.sum(jnp.where(w, dmin, 0.0))
 
 
 def _ref_chunks(n_ref: int, chunk: int) -> Tuple[np.ndarray, np.ndarray]:
@@ -333,6 +336,28 @@ def counted_dispatch(fn, dispatches: Dict[str, int], phase: str):
     return call
 
 
+@contextlib.contextmanager
+def span(name: str):
+    """Host span ``repro.<name>`` on the profiler's clock
+    (``jax.profiler.TraceAnnotation``): it lands in a device trace beside
+    the device's operations, and costs about a microsecond when no trace
+    is being taken.  Usable as a decorator."""
+    with jax.profiler.TraceAnnotation("repro." + name):
+        yield
+
+
+@contextlib.contextmanager
+def phase(name: str, wall_by_phase: Dict[str, float]):
+    """:func:`span` that also writes the phase's host wall-clock seconds
+    into ``wall_by_phase[name]`` (``FitReport.wall_by_phase``).  The wall
+    ends where the body ends, so a phase that must include its device
+    work ends its body in ``block_until_ready`` or a host read."""
+    t0 = time.perf_counter()
+    with span(name):
+        yield
+    wall_by_phase[name] = time.perf_counter() - t0
+
+
 def host_read(x):
     """The sanctioned device→host read point for the drivers.
 
@@ -342,9 +367,11 @@ def host_read(x):
     transfers): scattered ``float()``/``np.asarray()`` syncs would each
     be a separate, invisible transfer — and TRC001 findings if they
     leaked into jit-reachable code.  Accepts any pytree; returns numpy
-    leaves (Python scalars pass through unchanged).
+    leaves (Python scalars pass through unchanged).  Traced as the span
+    ``repro.host_read``.
     """
-    return jax.device_get(x)
+    with span("host_read"):
+        return jax.device_get(x)
 
 
 @contextlib.contextmanager
@@ -352,11 +379,12 @@ def host_stage(reason: str):
     """Sanctioned host→device staging span (input upload, RNG chain
     head, context construction).  The ``reason`` is mandatory, mirroring
     the tracecheck suppression policy: every allowed transfer window
-    names why it exists.  Inside the span the transfer guard is relaxed
-    to "allow"; everything outside stays at the caller's level."""
+    names why it exists, and names the span ``repro.stage(<reason>)``.
+    Inside the span the transfer guard is relaxed to "allow"; everything
+    outside stays at the caller's level."""
     if not reason:
         raise ValueError("host_stage requires a non-empty reason")
-    with jax.transfer_guard("allow"):
+    with span(f"stage({reason})"), jax.transfer_guard("allow"):
         yield
 
 

@@ -31,8 +31,10 @@ reference positions of every round the fit computed fresh — first
 materialisations and evicted-round replays alike — and a fresh
 evaluation costs ``n`` per position (a full column, which is what makes
 the position free for every arm of every later search that finds it
-resident).  Window-served rounds are tallied by ``adaptive_search`` as
-cached reads at the algorithmic ``count_fn·B`` rate.
+resident).  ``refresh_pos`` counts the replays alone: the part of
+``fresh_pos`` that a wider ring would have served.  Window-served rounds
+are tallied by ``adaptive_search`` as cached reads at the algorithmic
+``count_fn·B`` rate.
 
 The carried-moment reuse (virtual arms) reads the permutation *prefix*
 ``[0, c_rounds)`` of the cache; that prefix is resident — and ring slots
@@ -79,11 +81,14 @@ class PicCache(NamedTuple):
     computed fresh (materialisations + evicted-round replays); the fresh
     ledger of a search is ``n · Δfresh_pos`` (:func:`fresh_positions`,
     multiplied by ``n`` host-side).
+    ``refresh_pos`` — uint32, the evicted-round replays among them
+    (``FitReport.refresh_evals`` is ``n`` times it).
     """
 
     cols: jnp.ndarray
     hw: jnp.ndarray
     fresh_pos: jnp.ndarray
+    refresh_pos: jnp.ndarray
 
 
 def resolve_cache_rounds(n_rounds_max: int, batch_size: int,
@@ -121,7 +126,8 @@ def resolve_batch_cache_rounds(ns, batch_size: int,
 def make_cache(n_rows: int, block: int, rounds: int) -> PicCache:
     """Fresh all-cold cache: ``rounds`` ring slots of ``block`` columns."""
     return PicCache(cols=jnp.zeros((n_rows, rounds * block), jnp.float32),
-                    hw=jnp.int32(0), fresh_pos=jnp.uint32(0))
+                    hw=jnp.int32(0), fresh_pos=jnp.uint32(0),
+                    refresh_pos=jnp.uint32(0))
 
 
 def shard_slot_read_write(cols: jnp.ndarray, rnd, hw, block: int,
@@ -134,21 +140,29 @@ def shard_slot_read_write(cols: jnp.ndarray, rnd, hw, block: int,
     (``rnd ≥ hw`` — retaining an evicted replay would evict a newer
     round and break the trailing-window invariant).  Returns
     ``(block, cols')``; the caller advances ``hw``.
+
+    Device scopes: the ring's read, write and bookkeeping are ``pic``,
+    the fresh pass is ``fresh`` (kept apart, not nested).
     """
     W = cols.shape[1] // block
-    lo = jnp.maximum(hw - W, 0)
-    in_window = jnp.logical_and(rnd >= lo, rnd < hw)
-    slot = (rnd % W) * block
+    with jax.named_scope("pic"):
+        lo = jnp.maximum(hw - W, 0)
+        in_window = jnp.logical_and(rnd >= lo, rnd < hw)
+        slot = (rnd % W) * block
 
     def cached(c):
-        return jax.lax.dynamic_slice_in_dim(c, slot, block, 1), c
+        with jax.named_scope("pic"):
+            return jax.lax.dynamic_slice_in_dim(c, slot, block, 1), c
 
     def fresh(c):
-        dxy = compute_fresh()
-        c2 = jax.lax.cond(
-            rnd >= hw,
-            lambda cc: jax.lax.dynamic_update_slice_in_dim(cc, dxy, slot, 1),
-            lambda cc: cc, c)
+        with jax.named_scope("fresh"):
+            dxy = compute_fresh()
+        with jax.named_scope("pic"):
+            c2 = jax.lax.cond(
+                rnd >= hw,
+                lambda cc: jax.lax.dynamic_update_slice_in_dim(cc, dxy, slot,
+                                                               1),
+                lambda cc: cc, c)
         return dxy, c2
 
     return jax.lax.cond(in_window, cached, fresh, cols)
@@ -158,15 +172,21 @@ def cache_advance(cache: PicCache, cols, rnd, b_eff,
                   rounds_cap: int) -> PicCache:
     """Post-access bookkeeping shared by every PIC stats path (single
     device and sharded): charge ``b_eff`` fresh positions unless round
-    ``rnd`` was served from the resident window, and advance the
+    ``rnd`` was served from the resident window, charge them to
+    ``refresh_pos`` too when the round was materialised before and has
+    since been evicted (it lies below the window), and advance the
     high-water mark past it.  ``cols`` is the (possibly updated) ring
     buffer; ``rounds_cap`` its capacity ``W``.  The one definition of
     the window predicate + ledger rule."""
-    lo = jnp.maximum(cache.hw - rounds_cap, 0)
-    in_window = jnp.logical_and(rnd >= lo, rnd < cache.hw)
-    fresh_pos = cache.fresh_pos + jnp.where(
-        in_window, 0, b_eff).astype(jnp.uint32)
-    return PicCache(cols, jnp.maximum(cache.hw, rnd + 1), fresh_pos)
+    with jax.named_scope("pic"):
+        lo = jnp.maximum(cache.hw - rounds_cap, 0)
+        in_window = jnp.logical_and(rnd >= lo, rnd < cache.hw)
+        fresh_pos = cache.fresh_pos + jnp.where(
+            in_window, 0, b_eff).astype(jnp.uint32)
+        refresh_pos = cache.refresh_pos + jnp.where(
+            rnd < lo, b_eff, 0).astype(jnp.uint32)
+        return PicCache(cols, jnp.maximum(cache.hw, rnd + 1), fresh_pos,
+                        refresh_pos)
 
 
 def cache_read_or_write(be, data, ref_idx, *, metric: str, batch_size: int,

@@ -18,6 +18,15 @@ Ledger semantics (the paper's headline metric):
   count cache-served work and are excluded from ``distance_evals``;
   everything else is fresh.  Typical keys: ``build``, ``swap``,
   ``cache_warm``, ``build_cached``, ``swap_cached``.
+* ``refresh_evals`` — the part of ``distance_evals`` that recomputed PIC
+  columns the bounded ring had computed before and evicted (what a ring
+  holding every round would have served).  Not a phase key, so it moves
+  neither ``distance_evals`` nor the ledger.
+
+The benchmark reads ``distance_evals`` (``bench/metrics/fresh_evals.fit.py``),
+``refresh_evals`` (``bench/metrics/refresh_evals.fit.py``) and
+``wall_by_phase`` (``bench/metrics/build_s.fit.py``, ``swap_s.fit.py``);
+the serving layer's refit ledger reads :meth:`FitReport.ledger`.
 
 ``labels`` (the in-sample cluster assignment) is filled by the facade
 after the solve; solvers themselves only need medoids + loss + ledger.
@@ -46,12 +55,14 @@ class FitReport:
     build_rounds: List[int] = field(default_factory=list)
     swap_exact_fallbacks: int = 0
     cached_evals: int = 0   # evaluations served from a distance cache
+    refresh_evals: int = 0  # fresh evaluations of evicted PIC rounds
     labels: Optional[np.ndarray] = None
     solver: str = ""
     metric: str = ""
     # Wall-clock seconds per phase (e.g. "build" / "swap"), filled by
-    # solvers that time their phases (BanditPAM).  Unlike the ledger this
-    # is environment-dependent; benchmarks/core_bench.py medians it.
+    # solvers that time their phases (BanditPAM, through the host spans
+    # ``repro.build`` / ``repro.swap`` of ``engine.phase``).  Unlike the
+    # ledger this is environment-dependent.
     wall_by_phase: Dict[str, float] = field(default_factory=dict)
     # Driver-level compiled phase-step calls, MEASURED at the call site
     # (``engine.counted_dispatch``, not a self-reported constant): the
@@ -63,7 +74,7 @@ class FitReport:
 
     def ledger(self) -> Dict[str, object]:
         """The unified fresh/cached distance-evaluation ledger as one dict
-        (what ``benchmarks/run.py --json`` serialises per solver)."""
+        (what ``MedoidService``'s refit ledger records per fit)."""
         return {
             "fresh": int(self.distance_evals),
             "cached": int(self.cached_evals),

@@ -1,6 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels: padding, cropping,
 interpret-mode selection, and TPU deployment hooks.
 
+Everything a wrapper adds around its ``pallas_call`` (operand padding,
+one-hot, output crop) runs in the device scope ``prep``, so a device
+trace tells the wrappers' own cost from the kernels'.
+
 On this container (CPU) the kernels execute with ``interpret=True`` — the
 kernel bodies run in Python for correctness validation; on a real TPU
 backend the same code lowers to Mosaic.  ``install()`` re-registers the
@@ -67,12 +71,14 @@ def pairwise_distance(x: jnp.ndarray, y: jnp.ndarray, metric: str = "l2",
     m, r, d = x.shape[0], y.shape[0], x.shape[1]
     if dk % 128 != 0:
         raise ValueError(f"dk must be a lane multiple of 128, got {dk}")
-    xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
-    yp = _pad_to(_pad_to(y, 1, 128), 0, tr)
+    with jax.named_scope("prep"):
+        xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
+        yp = _pad_to(_pad_to(y, 1, 128), 0, tr)
     if d <= dk:
         out = _pairwise.pairwise_kernel(xp, yp, metric=metric, tm=tm, tr=tr,
                                         interpret=interpret)
-        return out[:m, :r]
+        with jax.named_scope("prep"):
+            return out[:m, :r]
 
     core = {"l2": "l2sq", "l2sq": "l2sq", "l1": "l1",
             "cosine": "dot"}.get(metric)
@@ -88,29 +94,32 @@ def pairwise_distance(x: jnp.ndarray, y: jnp.ndarray, metric: str = "l2",
         # dk-chunk into the jit.  The lane padding moves to the last
         # chunk's tail, where the zero features leave every partial sum
         # untouched.
-        xp = _pad_to(xp, 1, dk)
-        yp = _pad_to(yp, 1, dk)
+        with jax.named_scope("prep"):
+            xp = _pad_to(xp, 1, dk)
+            yp = _pad_to(yp, 1, dk)
         n_ch = xp.shape[1] // dk
 
         def body(c, acc):
-            xs = jax.lax.dynamic_slice_in_dim(xp, c * dk, dk, 1)
-            ys = jax.lax.dynamic_slice_in_dim(yp, c * dk, dk, 1)
+            with jax.named_scope("prep"):
+                xs = jax.lax.dynamic_slice_in_dim(xp, c * dk, dk, 1)
+                ys = jax.lax.dynamic_slice_in_dim(yp, c * dk, dk, 1)
             return acc + _pairwise.pairwise_kernel(
                 xs, ys, metric=core, tm=tm, tr=tr, interpret=interpret)
 
         acc = jax.lax.fori_loop(
             0, n_ch, body,
             jnp.zeros((xp.shape[0], yp.shape[0]), jnp.float32))
-    acc = acc[:m, :r]
-    if metric == "l2":
-        return jnp.sqrt(acc)
-    if metric == "cosine":
-        xf = x.astype(jnp.float32)
-        yf = y.astype(jnp.float32)
-        xn = jax.lax.rsqrt(jnp.maximum(jnp.sum(xf * xf, -1), 1e-30))
-        yn = jax.lax.rsqrt(jnp.maximum(jnp.sum(yf * yf, -1), 1e-30))
-        return 1.0 - acc * xn[:, None] * yn[None, :]
-    return acc
+    with jax.named_scope("prep"):
+        acc = acc[:m, :r]
+        if metric == "l2":
+            return jnp.sqrt(acc)
+        if metric == "cosine":
+            xf = x.astype(jnp.float32)
+            yf = y.astype(jnp.float32)
+            xn = jax.lax.rsqrt(jnp.maximum(jnp.sum(xf * xf, -1), 1e-30))
+            yn = jax.lax.rsqrt(jnp.maximum(jnp.sum(yf * yf, -1), 1e-30))
+            return 1.0 - acc * xn[:, None] * yn[None, :]
+        return acc
 
 
 def build_g_stats(x: jnp.ndarray, y: jnp.ndarray, dnear_b: jnp.ndarray,
@@ -122,18 +131,20 @@ def build_g_stats(x: jnp.ndarray, y: jnp.ndarray, dnear_b: jnp.ndarray,
     if interpret is None:
         interpret = _default_interpret()
     m = x.shape[0]
-    if lead_g is None:
-        lead_g = jnp.zeros_like(dnear_b)
-    xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
-    yp = _pad_to(_pad_to(y, 1, 128), 0, 128)
-    pad_b = yp.shape[0] - y.shape[0]
-    dn = jnp.pad(dnear_b, (0, pad_b))
-    wp = jnp.pad(w, (0, pad_b))               # padded refs get weight 0
-    lg = jnp.pad(lead_g, (0, pad_b))
+    with jax.named_scope("prep"):
+        if lead_g is None:
+            lead_g = jnp.zeros_like(dnear_b)
+        xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
+        yp = _pad_to(_pad_to(y, 1, 128), 0, 128)
+        pad_b = yp.shape[0] - y.shape[0]
+        dn = jnp.pad(dnear_b, (0, pad_b))
+        wp = jnp.pad(w, (0, pad_b))               # padded refs get weight 0
+        lg = jnp.pad(lead_g, (0, pad_b))
     sums, sq, cross = _build_g.build_g_kernel(xp, yp, dn, wp, lg,
                                               metric=metric, tm=tm,
                                               interpret=interpret)
-    return sums[:m], sq[:m], cross[:m]
+    with jax.named_scope("prep"):
+        return sums[:m], sq[:m], cross[:m]
 
 
 def _swap_prep(d1_b, d2_b, assign_b, w, k, lead_g, pad_b, row_mult=128):
@@ -162,14 +173,16 @@ def swap_g_stats(x: jnp.ndarray, y: jnp.ndarray, d1_b: jnp.ndarray,
     if interpret is None:
         interpret = _default_interpret()
     m = x.shape[0]
-    xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
-    yp = _pad_to(_pad_to(y, 1, 128), 0, 128)
-    d1, d2, oh, lg = _swap_prep(d1_b, d2_b, assign_b, w, k, lead_g,
-                                yp.shape[0] - y.shape[0])
+    with jax.named_scope("prep"):
+        xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
+        yp = _pad_to(_pad_to(y, 1, 128), 0, 128)
+        d1, d2, oh, lg = _swap_prep(d1_b, d2_b, assign_b, w, k, lead_g,
+                                    yp.shape[0] - y.shape[0])
     sums, sq, cross = _swap_g.swap_g_kernel(xp, yp, d1, d2, oh, lg,
                                             metric=metric, tm=tm,
                                             interpret=interpret)
-    return sums[:m, :k].T, sq[:m, :k].T, cross[:m, :k].T
+    with jax.named_scope("prep"):
+        return sums[:m, :k].T, sq[:m, :k].T, cross[:m, :k].T
 
 
 # Reference-axis tile budget for the cache-served SWAP kernel: one
@@ -203,9 +216,10 @@ def swap_g_stats_cached(dxy: jnp.ndarray, d1_b: jnp.ndarray,
     m, b = dxy.shape
 
     def one(dxy_c, d1_c, d2_c, a_c, w_c, lg_c):
-        dp = _pad_to(_pad_to(dxy_c, 1, 128), 0, tm)
-        d1, d2, oh, lg = _swap_prep(d1_c, d2_c, a_c, w_c, k, lg_c,
-                                    dp.shape[1] - dxy_c.shape[1])
+        with jax.named_scope("prep"):
+            dp = _pad_to(_pad_to(dxy_c, 1, 128), 0, tm)
+            d1, d2, oh, lg = _swap_prep(d1_c, d2_c, a_c, w_c, k, lg_c,
+                                        dp.shape[1] - dxy_c.shape[1])
         return _swap_g.swap_g_from_cache_kernel(dp, d1, d2, oh, lg, tm=tm,
                                                 interpret=interpret)
 
@@ -218,15 +232,19 @@ def swap_g_stats_cached(dxy: jnp.ndarray, d1_b: jnp.ndarray,
         # launch and the += merge order is fixed by the range().
         for lo in range(0, b, CACHE_B_MAX):
             hi = min(lo + CACHE_B_MAX, b)
-            part = one(dxy[:, lo:hi], d1_b[lo:hi], d2_b[lo:hi],
-                       assign_b[lo:hi], w[lo:hi],
-                       None if lead_g is None else lead_g[lo:hi])
-            if sums is None:
-                sums, sq, cross = part
-            else:
-                sums, sq, cross = (sums + part[0], sq + part[1],
-                                   cross + part[2])
-    return sums[:m, :k].T, sq[:m, :k].T, cross[:m, :k].T
+            with jax.named_scope("prep"):
+                chunk = (dxy[:, lo:hi], d1_b[lo:hi], d2_b[lo:hi],
+                         assign_b[lo:hi], w[lo:hi],
+                         None if lead_g is None else lead_g[lo:hi])
+            part = one(*chunk)
+            with jax.named_scope("prep"):
+                if sums is None:
+                    sums, sq, cross = part
+                else:
+                    sums, sq, cross = (sums + part[0], sq + part[1],
+                                       cross + part[2])
+    with jax.named_scope("prep"):
+        return sums[:m, :k].T, sq[:m, :k].T, cross[:m, :k].T
 
 
 # ---------------------------------------------------------------------------
@@ -284,20 +302,22 @@ def stream_build_g_stats(x: jnp.ndarray, yref: jnp.ndarray,
     m, d = x.shape
     r = yref.shape[0]
     tm, tb = _stream_tiles(m, d, 1, tm, tb)
-    if w is None:
-        w = jnp.ones((r,), jnp.float32)
-    if lead_g is None:
-        lead_g = jnp.zeros((r,), jnp.float32)
-    xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
-    yp = _pad_to(_pad_to(yref, 1, 128), 0, tb)
     _check_stream(tm, tb, d, 1, "stream_build_g_stats")
-    pad_r = yp.shape[0] - r
-    dn = jnp.pad(dnear, (0, pad_r))
-    wp = jnp.pad(w, (0, pad_r))               # padded refs get weight 0
-    lg = jnp.pad(lead_g, (0, pad_r))
+    with jax.named_scope("prep"):
+        if w is None:
+            w = jnp.ones((r,), jnp.float32)
+        if lead_g is None:
+            lead_g = jnp.zeros((r,), jnp.float32)
+        xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
+        yp = _pad_to(_pad_to(yref, 1, 128), 0, tb)
+        pad_r = yp.shape[0] - r
+        dn = jnp.pad(dnear, (0, pad_r))
+        wp = jnp.pad(w, (0, pad_r))               # padded refs get weight 0
+        lg = jnp.pad(lead_g, (0, pad_r))
     sums, sq, cross = _stream_g.stream_build_g_kernel(
         xp, yp, dn, wp, lg, metric=metric, tm=tm, tb=tb, interpret=interpret)
-    return sums[:m], sq[:m], cross[:m]
+    with jax.named_scope("prep"):
+        return sums[:m], sq[:m], cross[:m]
 
 
 def stream_swap_g_stats(x: jnp.ndarray, yref: jnp.ndarray, d1: jnp.ndarray,
@@ -316,17 +336,19 @@ def stream_swap_g_stats(x: jnp.ndarray, yref: jnp.ndarray, d1: jnp.ndarray,
     m, d = x.shape
     r = yref.shape[0]
     tm, tb = _stream_tiles(m, d, k, tm, tb)
-    if w is None:
-        w = jnp.ones((r,), jnp.float32)
-    xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
-    yp = _pad_to(_pad_to(yref, 1, 128), 0, tb)
     _check_stream(tm, tb, d, k, "stream_swap_g_stats")
-    d1p, d2p, oh, lg = _swap_prep(d1, d2, assign, w, k, lead_g,
-                                  yp.shape[0] - r, row_mult=tb)
+    with jax.named_scope("prep"):
+        if w is None:
+            w = jnp.ones((r,), jnp.float32)
+        xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
+        yp = _pad_to(_pad_to(yref, 1, 128), 0, tb)
+        d1p, d2p, oh, lg = _swap_prep(d1, d2, assign, w, k, lead_g,
+                                      yp.shape[0] - r, row_mult=tb)
     sums, sq, cross = _stream_g.stream_swap_g_kernel(
         xp, yp, d1p, d2p, oh, lg, metric=metric, tm=tm, tb=tb,
         interpret=interpret)
-    return sums[:m, :k].T, sq[:m, :k].T, cross[:m, :k].T
+    with jax.named_scope("prep"):
+        return sums[:m, :k].T, sq[:m, :k].T, cross[:m, :k].T
 
 
 def stream_top2(x: jnp.ndarray, med_pts: jnp.ndarray, *, metric: str = "l2",
@@ -342,13 +364,15 @@ def stream_top2(x: jnp.ndarray, med_pts: jnp.ndarray, *, metric: str = "l2",
     n, d = x.shape
     k = med_pts.shape[0]
     tm, tb = _stream_tiles(n, d, k, tm, None)
-    xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
-    mp = _pad_to(_pad_to(med_pts, 1, 128), 0, 128)
     _check_stream(tm, tb, d, k, "stream_top2")
-    kmask = jnp.pad(jnp.ones((k,), jnp.float32), (0, mp.shape[0] - k))
+    with jax.named_scope("prep"):
+        xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
+        mp = _pad_to(_pad_to(med_pts, 1, 128), 0, 128)
+        kmask = jnp.pad(jnp.ones((k,), jnp.float32), (0, mp.shape[0] - k))
     d1, d2, a = _stream_g.stream_top2_kernel(xp, mp, kmask, metric=metric,
                                              tm=tm, interpret=interpret)
-    return d1[:n], d2[:n], a[:n]
+    with jax.named_scope("prep"):
+        return d1[:n], d2[:n], a[:n]
 
 
 def install(metrics=("l2", "l2sq", "cosine", "l1")) -> None:

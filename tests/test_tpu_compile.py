@@ -213,7 +213,8 @@ def test_sharded_fit_compiles_for_four_chips(topo, reuse, phase):
         layout = [arg((r_max * b,), dt) for dt in (jnp.int32, jnp.float32)]
         cache = PicCache(cols=arg((n, s * w * b_loc), jnp.float32,
                                   P(None, "data")),
-                         hw=arg((), jnp.int32), fresh_pos=arg((), jnp.uint32))
+                         hw=arg((), jnp.int32), fresh_pos=arg((), jnp.uint32),
+                         refresh_pos=arg((), jnp.uint32))
         carry = (arg((K * n,), jnp.float32), arg((K * n,), jnp.float32),
                  arg((), jnp.int32), arg((n,), jnp.float32),
                  arg((n,), jnp.float32), arg((n,), jnp.int32))
