@@ -143,6 +143,18 @@ def _carry_delta(cols: jnp.ndarray, pidx: jnp.ndarray, pw: jnp.ndarray,
                 jnp.sum(w).astype(jnp.int32))
 
 
+def _pic_operand(data, data_p, *, backend: str, mode: str):
+    """The operand every fresh PIC round reads its column block from:
+    ``data`` as the stats backend aligns it (``align_rows`` — the Pallas
+    kernel's zero padding, nothing on jnp).  Each program makes it once,
+    at its top and outside every loop, unless an outer program passed
+    it down as ``data_p``.  None outside ``mode="pic"``, whose rounds
+    go through the fused stats wrappers instead."""
+    if mode != "pic" or data_p is not None:
+        return data_p
+    return get_stats_backend(backend).align_rows(data)
+
+
 # ---------------------------------------------------------------------------
 # BUILD
 # ---------------------------------------------------------------------------
@@ -151,8 +163,8 @@ def _build_step(data, dnear, med_mask, key, cache, dwarm, perm,
                 perm_idx=None, perm_w=None, valid=None, n_valid=None,
                 log_term=None, *,
                 backend: str, metric: str, batch_size: int, delta: float,
-                sampling: str, baseline: str, mode: str, free_rounds: int = 0
-                ) -> SearchResult:
+                sampling: str, baseline: str, mode: str, free_rounds: int = 0,
+                data_p=None) -> SearchResult:
     """One BUILD medoid selection (one Algorithm 1 call).
 
     ``mode`` is the cache regime (see :class:`FitContext`).  Under
@@ -166,7 +178,8 @@ def _build_step(data, dnear, med_mask, key, cache, dwarm, perm,
     logical n is ragged), the row-validity mask (pad rows may never
     become medoids), and the traced per-fit budget/δ
     (``n_valid``/``log_term``).  All default to None → the historical
-    single-fit trace, bit-identically.
+    single-fit trace, bit-identically.  ``data_p`` is the aligned PIC
+    operand (``_pic_operand``) when an outer program made it.
     """
     n = data.shape[0]
     be = get_stats_backend(backend)
@@ -176,9 +189,11 @@ def _build_step(data, dnear, med_mask, key, cache, dwarm, perm,
     ld = (lambda lead: lead) if baseline == "leader" else (lambda lead: None)
 
     if mode == "pic":
+        data_p = _pic_operand(data, data_p, backend=backend, mode=mode)
+
         def stats_fn(ref_idx, w, lead, rnd, aux):
             dxy, aux = cache_read_or_write(
-                be, data, ref_idx, metric=metric, batch_size=B, rnd=rnd,
+                be, data_p, ref_idx, metric=metric, batch_size=B, rnd=rnd,
                 b_eff=jnp.sum(w).astype(jnp.int32), cache=aux)
             with jax.named_scope("stats"):
                 s, q, c = be.build_stats_from_d(dxy, dnear[ref_idx], w,
@@ -251,11 +266,14 @@ _build_step_jit = jax.jit(
 def _build_fused(data, subkeys, cache, dwarm, perm, spidx=None, spw=None,
                  valid=None, n_valid=None, log_term=None, *, backend: str,
                  metric: str, batch_size: int, delta: float, sampling: str,
-                 baseline: str, k: int, mode: str, free_rounds: int):
+                 baseline: str, k: int, mode: str, free_rounds: int,
+                 data_p=None):
     """The whole BUILD phase as ONE jit: ``fori_loop`` over the k medoid
     selections, with d_near / the medoid mask / the bounded device PIC
     cache as loop carry.  Returns per-step rounds and the fresh/cached
-    ledger entries so the host never syncs mid-phase.
+    ledger entries so the host never syncs mid-phase.  Under ``"pic"``
+    the data set is aligned for the fresh rounds once, before the loop
+    (``_pic_operand``; ``data_p`` when ``_build_batch`` made it).
 
     ``spidx``/``spw`` (batched multi-fit lanes): explicit pre-tiled
     reference layouts — ``[k, R·B]`` for per-selection permutations
@@ -265,6 +283,8 @@ def _build_fused(data, subkeys, cache, dwarm, perm, spidx=None, spw=None,
     B = batch_size
     dist = get_metric(metric)
     pic = mode == "pic"
+    with jax.named_scope("build"):
+        data_p = _pic_operand(data, data_p, backend=backend, mode=mode)
 
     def body(i, c):
         dnear, med_mask, medoids, cc, rounds_a, evals_a, cached_a = c
@@ -276,7 +296,7 @@ def _build_fused(data, subkeys, cache, dwarm, perm, spidx=None, spw=None,
                          spidx_i, spw, valid, n_valid, log_term,
                          backend=backend, metric=metric, batch_size=B,
                          delta=delta, sampling=sampling, baseline=baseline,
-                         mode=mode, free_rounds=free_rounds)
+                         mode=mode, free_rounds=free_rounds, data_p=data_p)
         m = sr.best
         medoids = medoids.at[i].set(m)
         med_mask = med_mask.at[m].set(True)
@@ -318,21 +338,23 @@ def _swap_search(data, d1, d2, assign, med_mask, key, cache, dwarm, perm,
                  valid=None, n_valid=None, log_term=None, *, backend: str,
                  metric: str, batch_size: int, delta: float, k: int,
                  sampling: str, baseline: str, early_stop: bool, mode: str,
-                 free_rounds: int = 0) -> SearchResult:
+                 free_rounds: int = 0, data_p=None) -> SearchResult:
     """One SWAP best-arm search over the (medoid, candidate) arm set.
 
     The trailing optional args are the batched multi-fit lane state (see
     ``_build_step``); ``s_pidx``/``s_pw`` is this search's pre-tiled
-    reference layout."""
+    reference layout, ``data_p`` the aligned PIC operand."""
     n = data.shape[0]
     be = get_stats_backend(backend)
     B = batch_size
     ld = (lambda lead: lead) if baseline == "leader" else (lambda lead: None)
 
     if mode == "pic":
+        data_p = _pic_operand(data, data_p, backend=backend, mode=mode)
+
         def stats_fn(ref_idx, w, lead, rnd, aux):
             dxy, aux = cache_read_or_write(
-                be, data, ref_idx, metric=metric, batch_size=B, rnd=rnd,
+                be, data_p, ref_idx, metric=metric, batch_size=B, rnd=rnd,
                 b_eff=jnp.sum(w).astype(jnp.int32), cache=aux)
             with jax.named_scope("stats"):
                 s, q, c = be.swap_stats_from_d(dxy, d1[ref_idx], d2[ref_idx],
@@ -408,7 +430,8 @@ def _swap_iter(data, medoids, med_mask, key, cache, dwarm, perm, perm_idx,
                perm_w, carry, prev_loss, s_pidx=None, s_pw=None, valid=None,
                n_valid=None, log_term=None, *, backend: str, metric: str,
                batch_size: int, delta: float, k: int, sampling: str,
-               baseline: str, early_stop: bool, mode: str, free_rounds: int):
+               baseline: str, early_stop: bool, mode: str, free_rounds: int,
+               data_p=None):
     """One SWAP iteration as a single fused device step: medoid-cache
     refresh + carried-moment repair (``_carry_delta``) + bandit search +
     candidate loss + the accept decision against ``prev_loss``.  Only the
@@ -419,10 +442,13 @@ def _swap_iter(data, medoids, med_mask, key, cache, dwarm, perm, perm_idx,
     per-lane ``while_loop``, and keeping one definition for both paths
     is what makes ``fit_batch`` ≡ loop-of-``fit`` hold bit-for-bit at
     accept margins.  The trailing optional args are the batched lane
-    state (see ``_build_step``)."""
+    state (see ``_build_step``); under ``"pic"`` the fresh rounds' aligned
+    operand is made here, before the search, unless ``_swap_batch``
+    passed it as ``data_p``."""
     with jax.named_scope("swap"):
         n = data.shape[0]
         B = batch_size
+        data_p = _pic_operand(data, data_p, backend=backend, mode=mode)
         d1, d2, assign = medoid_cache(data, medoids, metric=metric)
         n_changed = jnp.int32(0)
         init_sums = init_sqsums = None
@@ -457,7 +483,7 @@ def _swap_iter(data, medoids, med_mask, key, cache, dwarm, perm, perm_idx,
                           backend=backend, metric=metric, batch_size=B,
                           delta=delta, k=k, sampling=sampling,
                           baseline=baseline, early_stop=early_stop,
-                          mode=mode, free_rounds=free_rounds)
+                          mode=mode, free_rounds=free_rounds, data_p=data_p)
         if mode == "pic":
             cache2 = sr.aux
             fresh = fresh_positions(cache, cache2)
@@ -528,22 +554,26 @@ def _build_batch(data, subkeys, cache, spidx, spw, valid, n_valid, log_term,
     """BUILD for a [batch] of padded fits: ONE jit, ``lax.map`` over the
     per-fit ``_build_fused`` lanes.  Every array input carries a leading
     batch axis (``cache`` is a stacked :class:`PicCache` pytree or None).
+    The PIC operand is aligned for all lanes at once, before the map.
     Returns stacked (med_mask, medoids, cache, rounds, fresh, cached)."""
 
     def lane(xs):
-        data_i, keys_i, cache_i, spidx_i, spw_i, valid_i, nv_i, lt_i = xs
+        (data_i, data_p_i, keys_i, cache_i, spidx_i, spw_i, valid_i, nv_i,
+         lt_i) = xs
         (dnear, med_mask, medoids, cc, rounds_a, evals_a,
          cached_a) = _build_fused(
              data_i, keys_i, cache_i, None, None, spidx_i, spw_i, valid_i,
              nv_i, lt_i, backend=backend, metric=metric,
              batch_size=batch_size, delta=delta, sampling=sampling,
-             baseline=baseline, k=k, mode=mode, free_rounds=free_rounds)
+             baseline=baseline, k=k, mode=mode, free_rounds=free_rounds,
+             data_p=data_p_i)
         del dnear  # not needed post-BUILD; keep the lane output lean
         return med_mask, medoids, cc, rounds_a, evals_a, cached_a
 
     with jax.named_scope("build"):
+        data_p = _pic_operand(data, None, backend=backend, mode=mode)
         return jax.lax.map(
-            lane, (data, subkeys, cache, spidx, spw, valid, n_valid,
+            lane, (data, data_p, subkeys, cache, spidx, spw, valid, n_valid,
                    log_term))
 
 
@@ -576,15 +606,16 @@ def _swap_batch(data, medoids, med_mask, subkeys, cache, pidx_c, pw_c,
     n_changed, exact_fallbacks, refresh, old[T], new[T], loss[T],
     accept[T]) — everything the host needs to assemble per-fit FitReports
     without a mid-phase sync; ``refresh`` is the ring's ``refresh_pos``
-    over the whole fit."""
+    over the whole fit.  The PIC operand is aligned for all lanes at once,
+    before the map."""
     n = data.shape[1]
     kn = k * n
     T = max_swaps
     pic = mode == "pic"
 
     def lane(xs):
-        (data_i, meds0, mask0, keys_i, cache_i, pidx_i, pw_i, spidx_i,
-         spw_i, valid_i, nv_i, lt_i) = xs
+        (data_i, data_p_i, meds0, mask0, keys_i, cache_i, pidx_i, pw_i,
+         spidx_i, spw_i, valid_i, nv_i, lt_i) = xs
         loss0 = total_loss(data_i, meds0, metric=metric, w=valid_i)
         if pic:
             carry0 = (jnp.zeros((kn,), jnp.float32),
@@ -608,7 +639,8 @@ def _swap_batch(data, medoids, med_mask, subkeys, cache, pidx_c, pw_c,
                  pw_i, carry, loss, pidx_t, spw_i, valid_i, nv_i, lt_i,
                  backend=backend, metric=metric, batch_size=batch_size,
                  delta=delta, k=k, sampling=sampling, baseline=baseline,
-                 early_stop=early_stop, mode=mode, free_rounds=free_rounds)
+                 early_stop=early_stop, mode=mode, free_rounds=free_rounds,
+                 data_p=data_p_i)
             x_idx = best % n
             meds2 = jnp.where(accept, cand, meds)
             mask2 = jnp.where(accept, new_mask, mask)
@@ -631,9 +663,10 @@ def _swap_batch(data, medoids, med_mask, subkeys, cache, pidx_c, pw_c,
                 stf[10], refresh, stf[11], stf[12], stf[13], stf[14])
 
     with jax.named_scope("swap"):
-        return jax.lax.map(lane, (data, medoids, med_mask, subkeys, cache,
-                                  pidx_c, pw_c, spidx, spw, valid, n_valid,
-                                  log_term))
+        data_p = _pic_operand(data, None, backend=backend, mode=mode)
+        return jax.lax.map(lane, (data, data_p, medoids, med_mask, subkeys,
+                                  cache, pidx_c, pw_c, spidx, spw, valid,
+                                  n_valid, log_term))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "T"))

@@ -38,7 +38,12 @@ one definition.  Backends are collective-free by contract: the sharded
 driver (``core.distributed``) calls ``pairwise`` + ``*_stats_from_d`` on
 shard-local blocks inside ``shard_map`` and composes the cross-shard
 ``psum`` itself, so every registered backend reaches the distributed path
-unchanged.  See docs/design.md for the numbered hardware adaptations.
+unchanged.  The single-device PIC programs call ``align_rows(data)`` once
+per program, outside every loop, and read each fresh column block as
+``pairwise(aligned, aligned[idx], rows=n)``; unaligned operands (the
+sharded fit's shard blocks, predict's row chunks) are padded inside
+``pairwise`` as before.  See docs/design.md for the numbered hardware
+adaptations.
 """
 
 from __future__ import annotations
@@ -397,11 +402,17 @@ class JnpStatsBackend:
 
     name = "jnp"
 
-    def pairwise(self, x, y, *, metric):
+    def align_rows(self, data):
+        """The data set as ``pairwise`` reads it best: unchanged here."""
+        return data
+
+    def pairwise(self, x, y, *, metric, rows=None):
+        """``[m, d] × [r, d] → [m, r]``; ``rows`` crops the output to the
+        logical rows of an ``align_rows`` operand (all of them here)."""
         # The jit'd entrypoint: inlined when already inside a trace, and
         # compiled (not op-by-op eager) for eager callers like the
         # chunked predict path.
-        return pairwise(x, y, metric=metric)
+        return pairwise(x, y, metric=metric)[:rows]
 
     # -- BUILD ----------------------------------------------------------
     def build_stats(self, data, ref_idx, dnear_b, w, lead, *, metric):
@@ -467,9 +478,23 @@ class PallasStatsBackend:
         self.interpret = interpret
         self.tm = tm
 
-    def pairwise(self, x, y, *, metric):
+    def align_rows(self, data):
+        """Zero-pad ``data`` to the pairwise kernel's row tile and
+        feature lanes (``ops.align_rows``), once per program: every
+        fresh PIC round then gathers its references from it, and
+        ``pairwise(aligned, aligned[idx], rows=n)`` pads nothing."""
         from repro.kernels import ops
-        return ops.pairwise_distance(x, y, metric=metric,
+        if data.shape[-1] > ops.DK_MAX:
+            # Past one kernel pass the wrapper re-chunks the features on
+            # every call anyway, and takes cosine's row norms from the
+            # operand as given, whose last bits zero columns would move.
+            return data
+        with jax.named_scope("prep"):
+            return ops.align_rows(data)
+
+    def pairwise(self, x, y, *, metric, rows=None):
+        from repro.kernels import ops
+        return ops.pairwise_distance(x, y, metric=metric, rows=rows,
                                      interpret=self.interpret)
 
     # -- BUILD ----------------------------------------------------------

@@ -189,19 +189,23 @@ def cache_advance(cache: PicCache, cols, rnd, b_eff,
                         refresh_pos)
 
 
-def cache_read_or_write(be, data, ref_idx, *, metric: str, batch_size: int,
-                        rnd, b_eff, cache: PicCache):
+def cache_read_or_write(be, data_p, ref_idx, *, metric: str,
+                        batch_size: int, rnd, b_eff, cache: PicCache):
     """One PIC cache access inside a single-device bandit round.
 
     Serve round ``rnd`` from the ring when resident, else compute the
     ``[n, B]`` block fresh through the backend's pairwise path (written
-    through only for new rounds).  ``b_eff`` is the round's effective
-    (non-padding) position count — the fresh-ledger increment when the
-    block is computed.  Returns ``(dxy, cache')``.
+    through only for new rounds).  ``data_p`` is the data set as
+    ``be.align_rows`` returns it, aligned once by the calling program
+    outside its loops; the fresh block gathers its references from it and
+    is cropped to the ring's ``n`` rows.  ``b_eff`` is the round's
+    effective (non-padding) position count — the fresh-ledger increment
+    when the block is computed.  Returns ``(dxy, cache')``.
     """
     dxy, cols = shard_slot_read_write(
         cache.cols, rnd, cache.hw, batch_size,
-        lambda: be.pairwise(data, data[ref_idx], metric=metric))
+        lambda: be.pairwise(data_p, data_p[ref_idx], metric=metric,
+                            rows=cache.cols.shape[0]))
     return dxy, cache_advance(cache, cols, rnd, b_eff,
                               cache.cols.shape[1] // batch_size)
 

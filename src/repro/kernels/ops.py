@@ -53,10 +53,27 @@ def _pad_to(a: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
     return jnp.pad(a, widths)
 
 
+def align_rows(x: jnp.ndarray, *, tm: int = 128) -> jnp.ndarray:
+    """Zero-pad ``x`` (``[..., m, d]``) to whole ``tm``-row tiles and
+    128-lane features: the ``x`` operand exactly as
+    ``pairwise_distance`` pads it.  A program that reads the same data
+    set in many kernel calls aligns it once and passes the result (with
+    ``rows=m``), so no call pads it again."""
+    return _pad_to(_pad_to(x, -1, 128), -2, tm)
+
+
 def pairwise_distance(x: jnp.ndarray, y: jnp.ndarray, metric: str = "l2",
-                      *, tm: int = 128, tr: int = 128, dk: int = DK_MAX,
+                      *, rows: Optional[int] = None, tm: int = 128,
+                      tr: int = 128, dk: int = DK_MAX,
                       interpret: Optional[bool] = None) -> jnp.ndarray:
     """[m, d] x [r, d] -> [m, r] via the tiled Pallas kernel.
+
+    ``x`` may come aligned by ``align_rows`` (and ``y`` gathered from
+    it): ``rows`` is then the logical row count ``m`` the output is
+    cropped to, and the padding is already in place, so none is added.
+    Within one kernel pass (``d <= dk``) the kernel sees the same tiles
+    either way, so the result is bit-identical; past it, cosine's row
+    norms also sum the zero columns, which can move their last bits.
 
     Feature dims up to ``dk`` are VMEM-resident in one kernel pass.  Past
     that budget the feature axis is split into ``dk``-column chunks and the
@@ -68,11 +85,12 @@ def pairwise_distance(x: jnp.ndarray, y: jnp.ndarray, metric: str = "l2",
     """
     if interpret is None:
         interpret = _default_interpret()
-    m, r, d = x.shape[0], y.shape[0], x.shape[1]
+    m = x.shape[0] if rows is None else rows
+    r, d = y.shape[0], x.shape[1]
     if dk % 128 != 0:
         raise ValueError(f"dk must be a lane multiple of 128, got {dk}")
     with jax.named_scope("prep"):
-        xp = _pad_to(_pad_to(x, 1, 128), 0, tm)
+        xp = align_rows(x, tm=tm)
         yp = _pad_to(_pad_to(y, 1, 128), 0, tr)
     if d <= dk:
         out = _pairwise.pairwise_kernel(xp, yp, metric=metric, tm=tm, tr=tr,
@@ -114,7 +132,7 @@ def pairwise_distance(x: jnp.ndarray, y: jnp.ndarray, metric: str = "l2",
         if metric == "l2":
             return jnp.sqrt(acc)
         if metric == "cosine":
-            xf = x.astype(jnp.float32)
+            xf = x[:m].astype(jnp.float32)
             yf = y.astype(jnp.float32)
             xn = jax.lax.rsqrt(jnp.maximum(jnp.sum(xf * xf, -1), 1e-30))
             yn = jax.lax.rsqrt(jnp.maximum(jnp.sum(yf * yf, -1), 1e-30))
