@@ -1,39 +1,42 @@
 """Seeded data of the benchmark's deployments, on the host.
 
-``mnist_like`` is a copy of the generator in ``src/repro/core/datasets.py``
-(at the commit that added this benchmark), kept here so that a change to
-the program's own files cannot move the yardstick.  It returns the same
-array as the original for the same arguments; ``tests/test_datagen.py``
-in this directory pins a few values.
+A configuration's ``dataset`` names the file ``datasets/<dataset>.py``,
+whose ``generate(n, seed, d)`` returns the data set's first ``n`` rows as
+a float32 ``[n, d]`` array.  Each such file is a copy of a generator of
+the program (it says which, and at which commit), kept here so that a
+change to the program's own files cannot move the yardstick;
+``tests/test_datagen.py`` in this directory pins a few values of each.
+A data set's generator is also an attribute of this module:
+``datagen.mnist_like`` is ``datasets/mnist_like.py``'s ``generate``.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-
-def mnist_like(n: int, seed: int = 0, d: int = 784, modes: int = 10,
-               zdim: int = 10) -> np.ndarray:
-    """Copy of ``repro.core.datasets.mnist_like``: a 10-mode mixture on a
-    10-d manifold embedded in 784-d, plus a noise floor, scaled to
-    [-1, 1]."""
-    rng = np.random.default_rng(seed)
-    zc = rng.standard_normal((modes, zdim)) * 4.0
-    w = rng.dirichlet(np.ones(modes) * 0.5)
-    z = zc[rng.choice(modes, size=n, p=w)] + rng.standard_normal((n, zdim))
-    q, _ = np.linalg.qr(rng.standard_normal((d, zdim)))
-    x = z @ q.T + 0.05 * rng.standard_normal((n, d))
-    return (x / np.abs(x).max()).astype(np.float32)
+from bench import harness
 
 
-GENERATORS = {"mnist_like": mnist_like}
+def generator(name: str):
+    """The ``generate`` of ``datasets/<name>.py``; ``LookupError`` naming
+    the data sets there are where it has none."""
+    return harness.load("datasets", name, "generate")
+
+
+def __getattr__(name: str):
+    if name.startswith("_"):
+        raise AttributeError(name)
+    try:
+        return generator(name)
+    except LookupError as e:
+        raise AttributeError(str(e)) from None
 
 
 def dataset(config: dict, n: int) -> np.ndarray:
     """The first ``n`` rows of a configuration's data set.  The data set
     is fixed by the configuration (``data_seed``), as a deployment's data
     is; a run's seed never changes it."""
-    gen = GENERATORS[config["dataset"]]
+    gen = generator(config["dataset"])
     return gen(n, seed=int(config["data_seed"]), d=int(config["d"]))
 
 

@@ -24,7 +24,7 @@ import numpy as np
 from bench import datagen, reference
 
 
-class FitJob:
+class Job:
     def __init__(self, config: dict, traffic: dict, seed: int):
         self.config = config
         self.traffic = traffic

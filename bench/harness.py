@@ -5,8 +5,12 @@ configuration is ``configs/<config>.json``, its traffic and the limits
 of its correctness check are ``workloads/<cell>.json``, and each of its
 per-layer metrics is read by ``metrics/<metric>.py`` (a function
 ``read(ctx)`` that returns a number, or ``None`` where it finds nothing
-to read).  The traffic names its job (``fit``: ``fit.py``).  Adding a
-cell or a metric adds files and entries only.
+to read).  The configuration names its data set, made by
+``datasets/<dataset>.py`` (``generate(n, seed, d)``, see ``datagen``),
+and the traffic names its job, the class ``Job`` of ``<job>.py``
+(``fit``: ``fit.py``).  Each is found by its name alone (``load``), so
+adding a cell, a metric, a data set or a job adds files and entries
+only.
 
 A run: check the device, enable the persistent compile cache in the
 checkout, set up (data, program, warm-up: ``setup_s``), measure for
@@ -22,6 +26,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 import shutil
 import sys
 import time
@@ -75,20 +80,38 @@ class Cell:
             return [w["name"] for w in json.load(f)["workloads"]]
 
 
-def make_job(cell: Cell, seed: int):
-    from bench.fit import FitJob
+def load(folder: str, name: str, attr: str):
+    """``attr`` of the file ``<folder>/<name>.py`` of the benchmark
+    (``folder`` ``""`` for the benchmark's own directory).  A name with
+    no such file, or a file without ``attr``, raises ``LookupError``
+    naming those that have it."""
+    where = os.path.join(HERE, folder)
+    path = os.path.join(where, name + ".py")
+    if os.path.isfile(path):
+        spec = importlib.util.spec_from_file_location(
+            f"bench_{folder or 'job'}_{name}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        if hasattr(mod, attr):
+            return getattr(mod, attr)
+    defines = re.compile(rf"^(def {attr}\(|class {attr}\b|{attr} = )", re.M)
+    have = []
+    for file in sorted(os.listdir(where)):
+        if file.endswith(".py"):
+            with open(os.path.join(where, file)) as f:
+                if defines.search(f.read()):
+                    have.append(file[:-3])
+    raise LookupError(f"{name!r}: no file {os.path.join(folder, name)}.py "
+                      f"with {attr!r}; the names that have one: {have}")
 
-    jobs = {"fit": FitJob}
-    return jobs[cell.traffic["job"]](cell.config, cell.traffic, seed)
+
+def make_job(cell: Cell, seed: int):
+    job = load("", cell.traffic["job"], "Job")
+    return job(cell.config, cell.traffic, seed)
 
 
 def read_metric(name: str, ctx: dict) -> Optional[float]:
-    path = os.path.join(HERE, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    value = mod.read(ctx)
+    value = load("metrics", name, "read")(ctx)
     return None if value is None else float(value)
 
 
