@@ -47,12 +47,17 @@ def expected_dispatches(report, *, warm: bool = False) -> Dict[str, int]:
     BUILD is a single fused dispatch (absent on warm starts); SWAP costs
     one dispatch per iteration — ``n_swaps`` accepted moves plus the
     final rejecting iteration when the fit converged rather than hitting
-    ``max_swaps``.
+    ``max_swaps``.  Under the certified stop (``reuse="none"``, whose
+    ledger has a ``certify`` phase) every rejected pick costs one exact
+    step more: each accepts a swap or ends the fit.
     """
     iters = report.n_swaps + (1 if report.converged else 0)
     exp = {"swap": iters}
     if not warm:
         exp["build"] = 1
+    certified = report.certify_swaps + (1 if report.converged else 0)
+    if "certify" in report.evals_by_phase and certified:
+        exp["certify"] = certified
     return exp
 
 
@@ -62,6 +67,7 @@ def jit_cache_sizes() -> Dict[str, int]:
     return {
         "_build_fused": bp._build_fused._cache_size(),
         "_swap_iter": bp._swap_iter_jit._cache_size(),
+        "_swap_certify": bp._swap_certify_jit._cache_size(),
         "_build_batch": bp._build_batch._cache_size(),
         "_swap_batch": bp._swap_batch._cache_size(),
     }

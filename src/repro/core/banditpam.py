@@ -13,7 +13,9 @@ Faithful to the paper:
 * σ_x re-estimated from the first batch of every Algorithm 1 call (Eq. 11,
   Appendix 1.2), B = 100, δ = 1/(1000·|S_tar|) by default (§3.2).
 * SWAP iterations repeat until the chosen swap no longer improves the exact
-  loss, with a hard cap T (paper §4 Remark 1).
+  loss, with a hard cap T (paper §4 Remark 1).  Under ``reuse="none"`` a
+  rejected bandit pick is checked by one exact SWAP step
+  (``_swap_certify``) before SWAP ends: the certified stop.
 
 Device-resident driver (docs/design.md hardware adaptation #5): the
 g-statistics are computed through a pluggable :class:`~repro.core.engine`
@@ -426,6 +428,12 @@ _swap_search_jit = jax.jit(
                                    "early_stop", "mode", "free_rounds"))
 
 
+def _accepts(new_loss, prev_loss):
+    """The one accept rule (f32, on device) shared by the single-fit
+    driver, every fit_batch lane and the certified stop."""
+    return new_loss < prev_loss - 1e-7 * jnp.maximum(1.0, jnp.abs(prev_loss))
+
+
 def _swap_iter(data, medoids, med_mask, key, cache, dwarm, perm, perm_idx,
                perm_w, carry, prev_loss, s_pidx=None, s_pw=None, valid=None,
                n_valid=None, log_term=None, *, backend: str, metric: str,
@@ -494,10 +502,7 @@ def _swap_iter(data, medoids, med_mask, key, cache, dwarm, perm, perm_idx,
         x_idx = sr.best % n
         cand = medoids.at[m_idx].set(x_idx)
         new_loss = total_loss(data, cand, metric=metric, w=valid)
-        # The one accept rule (f32, on device) shared by the single-fit
-        # driver and every fit_batch lane.
-        accept = new_loss < prev_loss - 1e-7 * jnp.maximum(1.0,
-                                                           jnp.abs(prev_loss))
+        accept = _accepts(new_loss, prev_loss)
         new_carry = (sr.sums, sr.sqsums, sr.rounds, d1, d2, assign)
         # The displaced medoid and the accepted-state mask are produced IN
         # TRACE so the host driver never does eager index arithmetic on
@@ -521,6 +526,75 @@ _swap_iter_jit = jax.jit(
                                  "k", "sampling", "baseline", "early_stop",
                                  "mode", "free_rounds"),
     donate_argnums=(4, 9))
+
+
+# ---------------------------------------------------------------------------
+# Certified stop (reuse="none", with or without the warm block)
+# ---------------------------------------------------------------------------
+
+def _swap_certify(data, medoids, med_mask, d1, d2, assign, prev_loss,
+                  valid=None, *, backend: str, metric: str, batch_size: int,
+                  k: int):
+    """One exact SWAP step, run under ``mode="none"``/``"warm"`` where the
+    bandit's pick failed the accept rule: SWAP ends only when this rejects too,
+    as PAM's own loop ends (``core/pam.py``).
+
+    On heavy-tailed g (zero-inflated l1 data) the bandit can eliminate
+    an improving arm that no σ estimated from a few samples separates,
+    so a bandit miss alone does not prove a swap optimum.  This step
+    sums g for all k·n (medoid, candidate) arms over every reference
+    point, with no elimination, through the same stats backend as a
+    fresh bandit round (``swap_stats`` over the data set in
+    ``batch_size``-column blocks); medoids (and a batched lane's pad
+    rows) are masked as ``pam()`` masks them, and the argmin's candidate
+    goes through the one accept rule.  ``d1``/``d2``/``assign`` are the
+    medoid cache of ``medoids`` that the rejected bandit step computed.
+
+    Both drivers call this one function: the single fit as a dispatch
+    of its own after a rejected pick, each ``fit_batch`` lane in a
+    ``lax.cond`` inside its loop.  Returns (best, new_loss, cand,
+    new_mask, old_med, accept), as ``_swap_iter`` returns them."""
+    with jax.named_scope("certify"):
+        n = data.shape[0]
+        be = get_stats_backend(backend)
+        idx_np, w_np = _ref_chunks(n, batch_size)
+        idx, w = jnp.asarray(idx_np), jnp.asarray(w_np)
+        if valid is not None:
+            w = w * valid[idx].astype(jnp.float32)
+
+        def block(sums, iw):
+            i, w_i = iw
+            s, _, _ = be.swap_stats(data, i, d1[i], d2[i], assign[i], w_i,
+                                    k, None, metric=metric)
+            return sums + s, None
+
+        sums, _ = jax.lax.scan(block, jnp.zeros((k * n,), jnp.float32),
+                               (idx, w))
+        cand_ok = jnp.logical_not(med_mask)
+        if valid is not None:
+            cand_ok = jnp.logical_and(cand_ok, valid)
+        arm_ok = jnp.tile(cand_ok[None, :], (k, 1)).reshape(-1)
+        best = jnp.argmin(jnp.where(arm_ok, sums, jnp.inf)).astype(jnp.int32)
+        m_idx = best // n
+        x_idx = best % n
+        cand = medoids.at[m_idx].set(x_idx)
+        new_loss = total_loss(data, cand, metric=metric, w=valid)
+        old_med = medoids[m_idx]
+        new_mask = med_mask.at[old_med].set(False).at[x_idx].set(True)
+        return (best, new_loss, cand, new_mask, old_med,
+                _accepts(new_loss, prev_loss))
+
+
+_swap_certify_jit = jax.jit(
+    _swap_certify, static_argnames=("backend", "metric", "batch_size", "k"))
+
+
+def _certify_evals(n: int, k: int) -> int:
+    """Fresh distance evaluations of one ``_swap_certify`` step: n² for
+    the exact pass (one distance per (x, y) pair serves all k arms, as
+    ``pam()`` counts FastPAM1) plus n·k for the candidate's loss, as a
+    bandit iteration counts its loss."""
+    return n * n + n * k
 
 
 # ---------------------------------------------------------------------------
@@ -602,16 +676,26 @@ def _swap_batch(data, medoids, med_mask, subkeys, cache, pidx_c, pw_c,
     ``adaptive_search`` re-derives σ from the first batch whenever
     ``n_used == 0``), which keeps the while-loop carry a fixed pytree.
 
+    Under ``reuse="none"`` a rejected pick runs ``_swap_certify`` in a
+    ``lax.cond``, as the single-fit driver does, and only its reject
+    ends the lane.
+
     Per lane returns (medoids, loss, converged, iters, fresh, cached,
-    n_changed, exact_fallbacks, refresh, old[T], new[T], loss[T],
-    accept[T]) — everything the host needs to assemble per-fit FitReports
-    without a mid-phase sync; ``refresh`` is the ring's ``refresh_pos``
+    n_changed, exact_fallbacks, certified, certify_swaps, refresh,
+    old[T], new[T], loss[T], accept[T]) — everything the host needs to
+    assemble per-fit FitReports without a mid-phase sync; ``certified``
+    and ``certify_swaps`` count the exact steps and their accepts (None
+    under ``reuse="pic"``), ``refresh`` is the ring's ``refresh_pos``
     over the whole fit.  The PIC operand is aligned for all lanes at once,
     before the map."""
     n = data.shape[1]
     kn = k * n
     T = max_swaps
     pic = mode == "pic"
+    certified = not pic
+    # The certified stop's counters ride the loop carry only where it
+    # runs; elsewhere they are leafless and the program is unchanged.
+    count0 = jnp.int32(0) if certified else None
 
     def lane(xs):
         (data_i, data_p_i, meds0, mask0, keys_i, cache_i, pidx_i, pw_i,
@@ -631,7 +715,8 @@ def _swap_batch(data, medoids, med_mask, subkeys, cache, pidx_c, pw_c,
 
         def body(st):
             (t, done, meds, mask, loss, carry, cc, fresh_s, cached_s,
-             nchg_s, exact_s, old_a, new_a, loss_a, acc_a) = st
+             nchg_s, exact_s, cert_s, cswap_s, old_a, new_a, loss_a,
+             acc_a) = st
             pidx_t = spidx_i if spidx_i.ndim == 1 else spidx_i[t]
             (best, new_loss, cand, new_mask, old, new_carry, cc2, fresh,
              cached, nchg, uexact, accept) = _swap_iter(
@@ -641,6 +726,24 @@ def _swap_batch(data, medoids, med_mask, subkeys, cache, pidx_c, pw_c,
                  delta=delta, k=k, sampling=sampling, baseline=baseline,
                  early_stop=early_stop, mode=mode, free_rounds=free_rounds,
                  data_p=data_p_i)
+            if certified:
+                # The certified stop of the single-fit driver: a rejected
+                # pick runs the one exact step, and only its reject ends
+                # the lane.
+                def keep(_):
+                    return (best, new_loss, cand, new_mask, old, accept,
+                            jnp.int32(0))
+
+                def exact(_):
+                    return _swap_certify(
+                        data_i, meds, mask, *new_carry[3:], loss, valid_i,
+                        backend=backend, metric=metric,
+                        batch_size=batch_size, k=k) + (jnp.int32(1),)
+
+                (best, new_loss, cand, new_mask, old, accept,
+                 ran) = jax.lax.cond(accept, keep, exact, None)
+                cert_s = cert_s + ran
+                cswap_s = cswap_s + ran * accept.astype(jnp.int32)
             x_idx = best % n
             meds2 = jnp.where(accept, cand, meds)
             mask2 = jnp.where(accept, new_mask, mask)
@@ -648,19 +751,20 @@ def _swap_batch(data, medoids, med_mask, subkeys, cache, pidx_c, pw_c,
                     jnp.where(accept, new_loss, loss),
                     new_carry if pic else None, cc2,
                     fresh_s + fresh, cached_s + cached, nchg_s + nchg,
-                    exact_s + uexact.astype(jnp.int32),
+                    exact_s + uexact.astype(jnp.int32), cert_s, cswap_s,
                     old_a.at[t].set(old), new_a.at[t].set(x_idx),
                     loss_a.at[t].set(new_loss), acc_a.at[t].set(accept))
 
         st0 = (jnp.int32(0), jnp.bool_(False), meds0, mask0, loss0,
                carry0, cache_i, jnp.uint32(0), jnp.uint32(0),
-               jnp.int32(0), jnp.int32(0),
+               jnp.int32(0), jnp.int32(0), count0, count0,
                jnp.zeros((T,), jnp.int32), jnp.zeros((T,), jnp.int32),
                jnp.zeros((T,), jnp.float32), jnp.zeros((T,), jnp.bool_))
         stf = jax.lax.while_loop(cond, body, st0)
         refresh = stf[6].refresh_pos if pic else jnp.uint32(0)
         return (stf[2], stf[4], stf[1], stf[0], stf[7], stf[8], stf[9],
-                stf[10], refresh, stf[11], stf[12], stf[13], stf[14])
+                stf[10], stf[11], stf[12], refresh, stf[13], stf[14],
+                stf[15], stf[16])
 
     with jax.named_scope("swap"):
         data_p = _pic_operand(data, None, backend=backend, mode=mode)
@@ -732,6 +836,15 @@ class BanditPAM:
     ``cache_width`` caps the ``reuse="pic"`` column ring (in reference
     columns, rounded down to round-batches; default a few dozen
     round-batches — see ``repro.core.pic_cache``).
+
+    ``reuse="none"`` ends SWAP on an exact check: a bandit pick that
+    fails the accept rule is followed by one exact SWAP step
+    (``_swap_certify``), and SWAP ends only when that step finds no
+    improving swap either, so the fit returns a swap optimum as PAM
+    does; the ``cache_cols`` warm block changes nothing there.  Its cost,
+    ``n² + n·k`` fresh evaluations per step (at least one per converged
+    fit), is the ledger's ``certify`` phase.  ``reuse="pic"`` keeps the
+    bandit's own stop.
     """
 
     def __init__(self, k: int, metric: str = "l2", batch_size: int = 100,
@@ -901,6 +1014,9 @@ class BanditPAM:
         step = counted_dispatch(
             _swap_iter_jit if self.fused else self._swap_iter_stepped,
             res.dispatches_by_phase, "swap")
+        certify = counted_dispatch(_swap_certify_jit,
+                                   res.dispatches_by_phase, "certify")
+        certified = 0
         for _ in range(self.max_swaps):
             with span("swap.iter"):
                 key, sub = jax.random.split(key)
@@ -927,11 +1043,27 @@ class BanditPAM:
             res.swap_exact_fallbacks += int(used_exact_h)
             if ctx.mode == "pic":
                 carry = new_carry
+            if ctx.mode != "pic" and not accept_h:
+                # Certified stop: a bandit miss ends SWAP only if an
+                # exact SWAP step finds no improving swap either.
+                with phase("swap.certify", res.wall_by_phase, key="certify"):
+                    d1, d2, assign = new_carry[3:]
+                    (best, new_loss_d, cand, new_mask, old_med,
+                     accept) = certify(
+                         data, medoids, med_mask, d1, d2, assign,
+                         prev_loss_d, backend=ctx.backend,
+                         metric=self.metric, batch_size=self.batch_size,
+                         k=self.k)
+                    best_h, new_loss_h, old_h, accept_h = host_read(
+                        (best, new_loss_d, old_med, accept))
+                certified += 1
+                res.certify_swaps += int(bool(accept_h))
             # The accept rule is evaluated ON DEVICE in f32 (inside
-            # _swap_iter) — the same comparison every fit_batch lane
-            # makes — so the two drivers cannot diverge at fp margins.
-            # On accept the driver only SELECTS the in-trace results
-            # (cand/new_mask); the running loss stays device-resident.
+            # _swap_iter and _swap_certify) — the same comparison every
+            # fit_batch lane makes — so the two drivers cannot diverge at
+            # fp margins.  On accept the driver only SELECTS the in-trace
+            # results (cand/new_mask); the running loss stays
+            # device-resident.
             if bool(accept_h):
                 x_idx = int(best_h) % n
                 medoids = cand
@@ -946,6 +1078,9 @@ class BanditPAM:
         res.evals_by_phase["swap"] = swap_evals
         if ctx.mode == "pic":
             res.evals_by_phase["swap_cached"] = swap_cached
+        if ctx.mode != "pic":
+            res.certify_evals = certified * _certify_evals(n, self.k)
+            res.evals_by_phase["certify"] = res.certify_evals
         return medoids, loss, converged
 
     def _swap_iter_stepped(self, data, medoids, med_mask, key, cache, dwarm,
@@ -992,9 +1127,7 @@ class BanditPAM:
         m_idx, x_idx = divmod(int(sr.best), n)
         cand = medoids.at[m_idx].set(x_idx)
         new_loss = total_loss(data, cand, metric=metric)
-        # Same f32 accept rule as the fused step (see _swap_iter).
-        accept = new_loss < prev_loss - 1e-7 * jnp.maximum(
-            1.0, jnp.abs(prev_loss))
+        accept = _accepts(new_loss, prev_loss)
         new_carry = (sr.sums, sr.sqsums, sr.rounds, d1, d2, assign)
         old_med = medoids[m_idx]
         new_mask = med_mask.at[old_med].set(False).at[x_idx].set(True)
@@ -1248,7 +1381,8 @@ class BanditPAM:
         with phase("swap", wall):
             sphase = counted_dispatch(_swap_batch, disp, "swap")
             (meds_f, loss_f, conv, iters, fresh_s, cached_s, nchg_s,
-             exact_s, refresh_s, old_a, new_a, loss_a, acc_a) = sphase(
+             exact_s, cert_s, cswap_s, refresh_s, old_a, new_a, loss_a,
+             acc_a) = sphase(
                  dataj, medoids, med_mask, skeys_j, ctx.cache,
                  ctx.perm_idx, ctx.perm_w, ctx.spidx_swap, ctx.spw,
                  ctx.valid, ctx.n_valid, ctx.log_swap,
@@ -1262,11 +1396,11 @@ class BanditPAM:
         # the batch driver mirrors the single-fit guard contract (one
         # dispatch per phase + sanctioned reads only).
         (meds_np, loss_np, conv_np, iters_np, rounds_np, bev_np, bca_np,
-         fresh_np, cached_np, nchg_np, exact_np, refresh_np, old_np, new_np,
-         la_np, acc_np) = host_read(
+         fresh_np, cached_np, nchg_np, exact_np, cert_np, cswap_np,
+         refresh_np, old_np, new_np, la_np, acc_np) = host_read(
             (meds_f, loss_f, conv, iters, rounds_a, evals_a, cached_a,
-             fresh_s, cached_s, nchg_s, exact_s, refresh_s, old_a, new_a,
-             loss_a, acc_a))
+             fresh_s, cached_s, nchg_s, exact_s, cert_s, cswap_s, refresh_s,
+             old_a, new_a, loss_a, acc_a))
         iters_np = np.asarray(iters_np, np.int64)
         rounds_np = np.asarray(rounds_np, np.int64)
         bev_np = np.asarray(bev_np, np.int64)
@@ -1294,6 +1428,10 @@ class BanditPAM:
                     int(cached_np[i]) + n_i * int(nchg_np[i]))
                 res.refresh_evals = n_i * int(refresh_np[i])
             res.swap_exact_fallbacks = int(exact_np[i])
+            if not pic:
+                res.certify_evals = int(cert_np[i]) * _certify_evals(n_i, k)
+                res.evals_by_phase["certify"] = res.certify_evals
+                res.certify_swaps = int(cswap_np[i])
             for t in range(it):
                 if acc_np[i, t]:
                     res.swap_history.append((int(old_np[i, t]),

@@ -352,15 +352,20 @@ def span(name: str):
 
 
 @contextlib.contextmanager
-def phase(name: str, wall_by_phase: Dict[str, float]):
-    """:func:`span` that also writes the phase's host wall-clock seconds
-    into ``wall_by_phase[name]`` (``FitReport.wall_by_phase``).  The wall
-    ends where the body ends, so a phase that must include its device
-    work ends its body in ``block_until_ready`` or a host read."""
+def phase(name: str, wall_by_phase: Dict[str, float],
+          key: Optional[str] = None):
+    """:func:`span` that also adds the phase's host wall-clock seconds to
+    ``wall_by_phase[key]`` (``FitReport.wall_by_phase``; ``key`` defaults
+    to ``name``), so a phase entered several times in one fit reports
+    its sum.  The wall ends where the body ends, so a phase that must
+    include its device work ends its body in ``block_until_ready`` or a
+    host read."""
+    key = name if key is None else key
     t0 = time.perf_counter()
     with span(name):
         yield
-    wall_by_phase[name] = time.perf_counter() - t0
+    wall_by_phase[key] = (wall_by_phase.get(key, 0.0)
+                          + time.perf_counter() - t0)
 
 
 def host_read(x):

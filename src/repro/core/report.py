@@ -22,10 +22,17 @@ Ledger semantics (the paper's headline metric):
   columns the bounded ring had computed before and evicted (what a ring
   holding every round would have served).  Not a phase key, so it moves
   neither ``distance_evals`` nor the ledger.
+* ``certify_evals`` — the fresh evaluations of the certified stop's exact
+  SWAP steps (``reuse="none"``: a bandit miss is checked by one exact
+  step before SWAP ends); also the phase key ``certify``, so a part of
+  ``distance_evals``.  ``certify_swaps`` counts the swaps such a step
+  accepted after a bandit miss.
 
 The benchmark reads ``distance_evals`` (``bench/metrics/fresh_evals.fit.py``),
-``refresh_evals`` (``bench/metrics/refresh_evals.fit.py``) and
-``wall_by_phase`` (``bench/metrics/build_s.fit.py``, ``swap_s.fit.py``);
+``refresh_evals`` (``bench/metrics/refresh_evals.fit.py``),
+``certify_evals`` (``bench/metrics/certify_evals.fit.py``) and
+``wall_by_phase`` (``bench/metrics/build_s.fit.py``, ``swap_s.fit.py``,
+``certify_s.fit.py``);
 the serving layer's refit ledger reads :meth:`FitReport.ledger`.
 
 ``labels`` (the in-sample cluster assignment) is filled by the facade
@@ -56,13 +63,16 @@ class FitReport:
     swap_exact_fallbacks: int = 0
     cached_evals: int = 0   # evaluations served from a distance cache
     refresh_evals: int = 0  # fresh evaluations of evicted PIC rounds
+    certify_evals: int = 0  # fresh evaluations of certified-stop steps
+    certify_swaps: int = 0  # swaps a certified-stop step accepted
     labels: Optional[np.ndarray] = None
     solver: str = ""
     metric: str = ""
     # Wall-clock seconds per phase (e.g. "build" / "swap"), filled by
     # solvers that time their phases (BanditPAM, through the host spans
-    # ``repro.build`` / ``repro.swap`` of ``engine.phase``).  Unlike the
-    # ledger this is environment-dependent.
+    # ``repro.build`` / ``repro.swap`` of ``engine.phase``; "certify" is
+    # the sum of the ``repro.swap.certify`` spans, a part of "swap").
+    # Unlike the ledger this is environment-dependent.
     wall_by_phase: Dict[str, float] = field(default_factory=dict)
     # Driver-level compiled phase-step calls, MEASURED at the call site
     # (``engine.counted_dispatch``, not a self-reported constant): the

@@ -183,9 +183,13 @@ def test_transfer_guard_full_fit(fit_guard, blobs, reuse):
     from repro.core.banditpam import BanditPAM
     report = fit_guard.fit(BanditPAM(3, seed=0, reuse=reuse), blobs)
     # The in-test dispatch contract: one fused BUILD dispatch, one
-    # dispatch per SWAP iteration (n_swaps accepts + converging reject).
+    # dispatch per SWAP iteration (n_swaps accepts + converging reject);
+    # under reuse="none" one exact certify step per rejected bandit pick.
     iters = report.n_swaps + (1 if report.converged else 0)
-    assert report.dispatches_by_phase == {"build": 1, "swap": iters}
+    want = {"build": 1, "swap": iters}
+    if reuse == "none":
+        want["certify"] = report.certify_swaps + 1
+    assert report.dispatches_by_phase == want
 
 
 def test_transfer_guard_warm_start(fit_guard, blobs):

@@ -133,8 +133,11 @@ def test_fused_matches_stepped(reuse):
 def test_wall_by_phase_reported():
     data = datasets.mnist_like(300, seed=0)
     b = BanditPAM(3, metric="l2", seed=0).fit(data)
-    assert set(b.wall_by_phase) == {"build", "swap"}
+    # reuse="none" ends SWAP on the certified stop's exact step, whose
+    # wall is a part of SWAP's
+    assert set(b.wall_by_phase) == {"build", "swap", "certify"}
     assert all(v > 0 for v in b.wall_by_phase.values())
+    assert b.wall_by_phase["certify"] <= b.wall_by_phase["swap"]
 
 
 # ---------------------------------------------------------------------------
